@@ -380,7 +380,6 @@ class ShardedKernelBackend:
     def _build_lookup(self):
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from repro.kernels.ops import sim_top1_raw
@@ -397,10 +396,10 @@ class ShardedKernelBackend:
             b = jnp.arange(gv.shape[1])
             return gv[win, b], win.astype(jnp.int32), gi[win, b]
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             local_top1, mesh=self._mesh,
             in_specs=(P(), P("cache"), P("cache")),
-            out_specs=(P(), P(), P()), check_rep=False))
+            out_specs=(P(), P(), P()), check_vma=False))
 
     def top1(self, store: ShardedStore, query: np.ndarray) -> tuple[int, float]:
         cids, sims = self.top1_batch(store, np.asarray(query)[None, :])
@@ -475,7 +474,6 @@ class ShardedKernelBackend:
         shard's ``ks`` survivors, hence below the merged ``km``-th)."""
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from repro.kernels.ops import sim_topk_q8_raw
@@ -499,10 +497,10 @@ class ShardedKernelBackend:
             mv, pos = jax.lax.top_k(allv, km)
             return mv, jnp.take_along_axis(alli, pos, axis=1)
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             local_qtopk, mesh=self._mesh,
             in_specs=(P(), P(), P("cache"), P("cache"), P("cache")),
-            out_specs=(P(), P()), check_rep=False))
+            out_specs=(P(), P()), check_vma=False))
 
     def _top1_batch_quantized(self, store: ShardedStore, queries: np.ndarray
                               ) -> tuple[np.ndarray, np.ndarray]:
@@ -660,7 +658,6 @@ class ShardedKernelBackend:
     def _build_multi_lookup(self):
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from repro.kernels.ops import sim_top1_multi_raw
@@ -679,10 +676,10 @@ class ShardedKernelBackend:
             b = jnp.arange(gv.shape[2])[None, :]
             return gv[win, p, b], win.astype(jnp.int32), gi[win, p, b]
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             local_multi, mesh=self._mesh,
             in_specs=(P(), P("cache"), P("cache")),
-            out_specs=(P(), P(), P()), check_rep=False))
+            out_specs=(P(), P(), P()), check_vma=False))
 
     def top1_multi(self, arena, queries: np.ndarray
                    ) -> tuple[np.ndarray, np.ndarray]:
@@ -793,7 +790,6 @@ class ShardedKernelBackend:
     # ------------------------------------------------------------- eviction
     def _build_rac(self, alpha: float):
         import jax
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from repro.kernels.ops import rac_value_raw
@@ -804,10 +800,10 @@ class ShardedKernelBackend:
             return rac_value_raw(tsi, tid, tp_last, t_last, alpha, 0,
                                  use_pallas=use_pallas, interpret=interpret)
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             local_rac, mesh=self._mesh,
             in_specs=(P("cache"), P("cache"), P(), P()),
-            out_specs=P("cache"), check_rep=False))
+            out_specs=P("cache"), check_vma=False))
 
     def rac_value(self, tsi, tids, tp_last, t_last, alpha, t_now):
         """Per-shard Eq. 1 scoring over the resident-table entry axis.
@@ -846,7 +842,6 @@ class ShardedKernelBackend:
     def _build_decide(self, alpha: float):
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from repro.kernels.ops import fused_decide_raw
@@ -866,12 +861,12 @@ class ShardedKernelBackend:
             return (gv[win, b], win.astype(jnp.int32), gi[win, b],
                     rv, ri, vv)
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             local_decide, mesh=self._mesh,
             in_specs=(P(), P("cache"), P("cache"), P(), P(), P("cache"),
                       P("cache"), P("cache"), P(), P(), P()),
             out_specs=(P(), P(), P(), P(), P(), P("cache")),
-            check_rep=False))
+            check_vma=False))
 
     def decide_batch(self, store: ShardedStore, table, queries, *,
                      alpha=0.0, t_now=0):

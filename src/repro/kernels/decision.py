@@ -19,8 +19,9 @@ fused path needs:
     directly, so the min-value victim scan can run on the fixed-shape slot
     table without a host-side where().
 
-Tiling matches ``rac_value``: entries stream in tiles of BN with the
-per-topic tables VMEM-resident and gathered per tile.
+Tiling matches ``rac_value``: the per-topic tables are gathered per
+entry by XLA before the kernel (Mosaic has no 1-D gather), and the kernel
+is elementwise over (8, 128) tiles of the slot axis.
 """
 from __future__ import annotations
 
@@ -31,55 +32,50 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-BN = 1024     # entries per tile
+LANES = 128
+BN = 8 * LANES   # entries per tile
 
 
-def _victim_value_kernel(tn_ref, tsi_ref, tid_ref, occ_ref, tp_ref, tl_ref,
-                         out_ref, *, alpha: float):
+def _victim_value_kernel(tn_ref, tsi_ref, tp_ref, tl_ref, occ_ref, out_ref,
+                         *, alpha: float):
     t_now = tn_ref[0]
-    tid = jnp.maximum(tid_ref[...], 0)         # free slots carry tid -1
-    tp_last = jnp.take(tp_ref[...], tid, axis=0)
-    t_last = jnp.take(tl_ref[...], tid, axis=0)
     # subtract in int32 first: only the (small) age is cast, so absolute
     # timestamps past float32's 2^24 integer range never lose precision
-    decay = jnp.exp2(-alpha * (t_now - t_last).astype(jnp.float32))
-    val = decay * tp_last * tsi_ref[...]
+    decay = jnp.exp2(-alpha * (t_now - tl_ref[...]).astype(jnp.float32))
+    val = decay * tp_ref[...] * tsi_ref[...]
     out_ref[...] = jnp.where(occ_ref[...] > 0, val, jnp.inf)
 
 
 def victim_value_pallas(tsi: jnp.ndarray, tid: jnp.ndarray,
                         occ: jnp.ndarray, tp_last: jnp.ndarray,
                         t_last: jnp.ndarray, t_now, alpha: float, *,
-                        interpret: bool = True):
+                        interpret: bool):
     """tsi (N,) f32; tid (N,) i32; occ (N,) i32 (0 = free → +inf);
     tp_last/t_last (T,) topic tables; ``t_now`` a runtime int32 scalar.
     N must be a BN multiple (pad tsi/tid with 0 and occ with 0)."""
     n = tsi.shape[0]
-    t = tp_last.shape[0]
     assert n % BN == 0
-    kernel = functools.partial(_victim_value_kernel, alpha=alpha)
+    tid = jnp.maximum(tid, 0)                  # free slots carry tid -1
+    cols = (tsi, jnp.take(tp_last.astype(jnp.float32), tid),
+            jnp.take(t_last.astype(jnp.int32), tid), occ)
+    spec = pl.BlockSpec((BN // LANES, LANES), lambda i, tn: (i, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n // BN,),
-        in_specs=[pl.BlockSpec((BN,), lambda i, tn: (i,)),
-                  pl.BlockSpec((BN,), lambda i, tn: (i,)),
-                  pl.BlockSpec((BN,), lambda i, tn: (i,)),
-                  pl.BlockSpec((t,), lambda i, tn: (0,)),
-                  pl.BlockSpec((t,), lambda i, tn: (0,))],
-        out_specs=pl.BlockSpec((BN,), lambda i, tn: (i,)))
-    return pl.pallas_call(
-        kernel,
+        num_scalar_prefetch=1, grid=(n // BN,),
+        in_specs=[spec] * len(cols), out_specs=spec)
+    out = pl.pallas_call(
+        functools.partial(_victim_value_kernel, alpha=alpha),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n // LANES, LANES), jnp.float32),
         interpret=interpret,
-    )(jnp.asarray(t_now, jnp.int32).reshape(1), tsi, tid, occ,
-      tp_last.astype(jnp.float32), t_last.astype(jnp.int32))
+    )(jnp.asarray(t_now, jnp.int32).reshape(1),
+      *(c.reshape(n // LANES, LANES) for c in cols))
+    return out.reshape(n)
 
 
 def victim_value_multi_pallas(tsi: jnp.ndarray, tid: jnp.ndarray,
                               occ: jnp.ndarray, tp_last: jnp.ndarray,
                               t_last: jnp.ndarray, t_now, alpha: float, *,
-                              interpret: bool = True):
+                              interpret: bool):
     """Policy-stacked victim scoring: one dispatch scores P slot tables.
 
     All slot-axis inputs carry a leading policy axis — tsi/tid/occ

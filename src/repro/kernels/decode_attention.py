@@ -51,7 +51,7 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, *, bk: int,
 
 
 def decode_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                            pos: jnp.ndarray, *, interpret: bool = True):
+                            pos: jnp.ndarray, *, interpret: bool):
     """q (B, H, D); k/v (B, S, Hkv, D); pos (B,) int32 — index of the
     newest valid cache entry (attend to [0, pos])."""
     b, h, d = q.shape

@@ -54,7 +54,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, bk: int, scale: float):
 
 
 def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                           *, interpret: bool = True) -> jnp.ndarray:
+                           *, interpret: bool) -> jnp.ndarray:
     """q (B, H, S, D); k/v (B, Hkv, S, D); S % BQ == 0; causal."""
     b, h, s, d = q.shape
     hkv = k.shape[1]

@@ -40,8 +40,9 @@ candidate width to a
 geometric grid (powers of two plus the 1.5× midpoints, floor 64) sized
 from the top-``P`` bucket counts and the probe budget, so a steady-state
 chunk loop compiles once per bucket and re-uses that executable for the
-rest of the run.  Scratch (query) buffers are donated on accelerators;
-on CPU donation is skipped (XLA CPU ignores it and warns).
+rest of the run.  No buffer is donated: the outputs are per-row vectors
+that no query buffer could alias, and importing this module must not
+pick a backend.
 """
 from __future__ import annotations
 
@@ -51,8 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .ops import (_is_cpu, count_launch, route_topics_raw, sim_top1_raw,
-                  sim_topk_q8_raw)
+from .ops import count_launch, route_topics_raw, sim_top1_raw, sim_topk_q8_raw
 from .quant import quantize_rows_int8
 
 #: Shortlist width when the pruned path runs without a composed
@@ -340,18 +340,13 @@ def _fused_quant_body(qp, q8q, qsc, ql1, emb, q8s, csc, cl1, n_valid, b_real,
     return win, rmax, cert, n_u
 
 
-# Query buffers are per-call scratch → donate them on accelerators; XLA
-# CPU ignores donation (and warns), so skip it there.
-_DONATE = () if _is_cpu() else (0, 1, 2, 3)
+_fused_pruned_jit = jax.jit(
+    _fused_pruned_body, static_argnames=("probes", "cap_c", "k", "armed",
+                                         "use_pallas", "interpret"))
 
-_fused_pruned_jit = functools.partial(
-    jax.jit, static_argnames=("probes", "cap_c", "k", "armed", "use_pallas",
-                              "interpret"),
-    donate_argnums=_DONATE)(_fused_pruned_body)
-
-_fused_quant_jit = functools.partial(
-    jax.jit, static_argnames=("k", "armed", "use_pallas", "interpret"),
-    donate_argnums=_DONATE)(_fused_quant_body)
+_fused_quant_jit = jax.jit(
+    _fused_quant_body, static_argnames=("k", "armed", "use_pallas",
+                                        "interpret"))
 
 
 def fused_pruned_lookup(qp, q8q, qsc, ql1, emb, q8s, csc, cl1, aug, indptr,

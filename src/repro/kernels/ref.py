@@ -4,11 +4,16 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+# Similarity scores are exact fp32 products, as in the kernels: XLA's
+# default f32 matmul on TPU rounds its operands to bf16.
+_F32 = jax.lax.Precision.HIGHEST
+
 
 def sim_top1_ref(queries: jnp.ndarray, candidates: jnp.ndarray,
                  n_valid: int):
     """queries (Q,D), candidates (N,D) -> (max sim (Q,), argmax (Q,))."""
-    scores = queries.astype(jnp.float32) @ candidates.astype(jnp.float32).T
+    scores = jnp.dot(queries.astype(jnp.float32),
+                     candidates.astype(jnp.float32).T, precision=_F32)
     col = jnp.arange(candidates.shape[0])
     scores = jnp.where(col[None, :] < n_valid, scores, -jnp.inf)
     return scores.max(axis=1), scores.argmax(axis=1).astype(jnp.int32)
@@ -19,7 +24,8 @@ def sim_topk_ref(queries: jnp.ndarray, candidates: jnp.ndarray,
     """queries (Q,D), candidates (N,D) -> (vals (Q,K), idx (Q,K)), sorted
     descending; ``lax.top_k`` breaks ties toward the lower index, matching
     the kernel's merge order and a stable descending host sort."""
-    scores = queries.astype(jnp.float32) @ candidates.astype(jnp.float32).T
+    scores = jnp.dot(queries.astype(jnp.float32),
+                     candidates.astype(jnp.float32).T, precision=_F32)
     col = jnp.arange(candidates.shape[0])
     scores = jnp.where(col[None, :] < n_valid, scores, -jnp.inf)
     vals, idx = jax.lax.top_k(scores, k)

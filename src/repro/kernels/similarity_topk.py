@@ -33,6 +33,22 @@ from jax.experimental.pallas import tpu as pltpu
 BQ = 128      # query tile
 BC = 512      # candidate tile
 
+# fp32 scores must be exact fp32 products: the safety predicates of the
+# quantized and pruned paths bound error at fp32 scale, and the host
+# oracle scores in fp32.  On a v5e, Mosaic's default contraction rounds
+# f32 operands to bf16 (errors near 4e-4 for unit rows at D=384), so the
+# precision is pinned.
+_F32 = jax.lax.Precision.HIGHEST
+
+
+def _first_max_index(x, m, index):
+    """Lowest ``index`` per row among the entries of ``x`` equal to the row
+    max ``m`` (BQ, 1).  ``jnp.argmax`` on the chip does not promise the
+    first of equal maxima (an all -inf row came back as its last lane), so
+    the lower-index tie rule is spelled out."""
+    big = jnp.iinfo(jnp.int32).max
+    return jnp.min(jnp.where(x == m, index, big), axis=1)[:, None]
+
 
 def _sim_top1_kernel(nv_ref, q_ref, c_ref, val_ref, idx_ref):
     """grid = (nq, nc); candidate axis is a sequential reduction.
@@ -44,12 +60,12 @@ def _sim_top1_kernel(nv_ref, q_ref, c_ref, val_ref, idx_ref):
     q = q_ref[...]                                   # (BQ, D)
     c = c_ref[...]                                   # (BC, D)
     scores = jax.lax.dot_general(
-        q, c, (((1,), (1,)), ((), ())),
+        q, c, (((1,), (1,)), ((), ())), precision=_F32,
         preferred_element_type=jnp.float32)          # (BQ, BC) on the MXU
     col = j * BC + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
     scores = jnp.where(col < n_valid, scores, -jnp.inf)
-    m = jnp.max(scores, axis=1)
-    a = j * BC + jnp.argmax(scores, axis=1).astype(jnp.int32)
+    m = jnp.max(scores, axis=1)[:, None]
+    a = _first_max_index(scores, m, col)
 
     @pl.when(j == 0)
     def _init():
@@ -65,11 +81,15 @@ def _sim_top1_kernel(nv_ref, q_ref, c_ref, val_ref, idx_ref):
 
 
 def sim_top1_pallas(queries: jnp.ndarray, candidates: jnp.ndarray,
-                    n_valid, *, interpret: bool = True):
+                    n_valid, *, interpret: bool):
     """queries (Q, D), candidates (N, D) both padded to tile multiples;
     returns (vals (Q,), idx (Q,)).  ``n_valid`` is a runtime scalar (python
     int or traced int32) masking the candidate tail — free slots beyond the
-    resident high-water mark and padding rows never win Top-1."""
+    resident high-water mark and padding rows never win Top-1.
+
+    The kernel writes (Q, 1) columns: a 1-D output's tiling in XLA grows
+    with its length (512 for Q=384) while Mosaic tiles a (BQ,) block by
+    128, which the TPU compiler refuses."""
     q_n, d = queries.shape
     c_n = candidates.shape[0]
     assert q_n % BQ == 0 and c_n % BC == 0 and d % 128 == 0
@@ -78,23 +98,24 @@ def sim_top1_pallas(queries: jnp.ndarray, candidates: jnp.ndarray,
         grid=(q_n // BQ, c_n // BC),
         in_specs=[pl.BlockSpec((BQ, d), lambda i, j, nv: (i, 0)),
                   pl.BlockSpec((BC, d), lambda i, j, nv: (j, 0))],
-        out_specs=[pl.BlockSpec((BQ,), lambda i, j, nv: (i,)),
-                   pl.BlockSpec((BQ,), lambda i, j, nv: (i,))])
-    return pl.pallas_call(
+        out_specs=[pl.BlockSpec((BQ, 1), lambda i, j, nv: (i, 0)),
+                   pl.BlockSpec((BQ, 1), lambda i, j, nv: (i, 0))])
+    vals, idx = pl.pallas_call(
         _sim_top1_kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((q_n,), jnp.float32),
-                   jax.ShapeDtypeStruct((q_n,), jnp.int32)],
+        out_shape=[jax.ShapeDtypeStruct((q_n, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((q_n, 1), jnp.int32)],
         interpret=interpret,
     )(jnp.asarray(n_valid, jnp.int32).reshape(1), queries, candidates)
+    return vals[:, 0], idx[:, 0]
 
 def _topk_fold(k: int, j, scores, col, val_ref, idx_ref):
     """Fold one masked score tile into the running per-query Top-K held in
     the revisited output block: K select-and-mask passes over the
     ``[running | tile]`` concatenation.  The running list is sorted
     descending with ties already resolved toward lower candidate index,
-    and it sits left of the (higher-index) tile columns, so argmax's
-    first-occurrence tie break keeps "lower candidate index wins"
+    and it sits left of the (higher-index) tile columns, so taking the
+    first lane of equal maxima keeps "lower candidate index wins"
     globally.  Shared by the fp32 and int8 Top-K kernels — survivor sets
     are therefore selected identically in both."""
 
@@ -108,12 +129,11 @@ def _topk_fold(k: int, j, scores, col, val_ref, idx_ref):
     new_v, new_i = [], []
     lane = jax.lax.broadcasted_iota(jnp.int32, comb_v.shape, 1)
     for _ in range(k):
-        m = jnp.max(comb_v, axis=1)                  # (BQ,)
-        a = jnp.argmax(comb_v, axis=1).astype(jnp.int32)
-        hit = lane == a[:, None]
+        m = jnp.max(comb_v, axis=1)[:, None]         # (BQ, 1)
+        hit = lane == _first_max_index(comb_v, m, lane)
         # one-hot max instead of gather: the selected lane's index
         # (indices are >= 0, so the -1 fill never wins)
-        new_v.append(m)
+        new_v.append(m[:, 0])
         new_i.append(jnp.max(jnp.where(hit, comb_i, -1), axis=1))
         comb_v = jnp.where(hit, -jnp.inf, comb_v)
     val_ref[...] = jnp.stack(new_v, axis=1)
@@ -132,7 +152,7 @@ def _make_sim_topk_kernel(k: int):
         q = q_ref[...]                                   # (BQ, D)
         c = c_ref[...]                                   # (BC, D)
         scores = jax.lax.dot_general(
-            q, c, (((1,), (1,)), ((), ())),
+            q, c, (((1,), (1,)), ((), ())), precision=_F32,
             preferred_element_type=jnp.float32)          # (BQ, BC) on the MXU
         col = j * BC + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
         scores = jnp.where(col < n_valid, scores, -jnp.inf)
@@ -158,8 +178,7 @@ def _make_sim_topk_q8_kernel(k: int):
         acc = jax.lax.dot_general(
             q, c, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.int32)            # exact int32 scores
-        scores = (acc.astype(jnp.float32)
-                  * qs_ref[...][:, None]) * cs_ref[...][None, :]
+        scores = (acc.astype(jnp.float32) * qs_ref[...]) * cs_ref[...]
         col = j * BC + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
         scores = jnp.where(col < n_valid, scores, -jnp.inf)
         _topk_fold(k, j, scores, col, val_ref, idx_ref)
@@ -168,7 +187,7 @@ def _make_sim_topk_q8_kernel(k: int):
 
 
 def sim_topk_pallas(queries: jnp.ndarray, candidates: jnp.ndarray,
-                    n_valid, k: int, *, interpret: bool = True):
+                    n_valid, k: int, *, interpret: bool):
     """queries (Q, D), candidates (N, D) padded to tile multiples; returns
     (vals (Q, K), idx (Q, K)) sorted descending, ties toward the lower
     candidate index.  ``n_valid`` is a runtime scalar masking the candidate
@@ -196,12 +215,16 @@ def sim_topk_pallas(queries: jnp.ndarray, candidates: jnp.ndarray,
 
 def sim_topk_q8_pallas(q8: jnp.ndarray, qscale: jnp.ndarray,
                        c8: jnp.ndarray, cscale: jnp.ndarray,
-                       n_valid, k: int, *, interpret: bool = True):
+                       n_valid, k: int, *, interpret: bool):
     """Top-K over a per-row-quantized slab: ``q8`` (Q, D) int8 with
     ``qscale`` (Q,) fp32, ``c8`` (N, D) int8 with ``cscale`` (N,) fp32,
     all padded to tile multiples (zero rows quantize to zero, so padding
     is exact).  Returns (vals (Q, K), idx (Q, K)) of *approximate* fp32
-    similarities, same ordering/tie contract as ``sim_topk_pallas``."""
+    similarities, same ordering/tie contract as ``sim_topk_pallas``.
+
+    The scales enter the kernel as (Q, 1) and (1, N) blocks: a 1-D
+    operand's tiling in XLA (1024) differs from Mosaic's (512), which the
+    TPU compiler refuses."""
     q_n, d = q8.shape
     c_n = c8.shape[0]
     assert q_n % BQ == 0 and c_n % BC == 0 and d % 128 == 0
@@ -210,9 +233,9 @@ def sim_topk_q8_pallas(q8: jnp.ndarray, qscale: jnp.ndarray,
         num_scalar_prefetch=1,
         grid=(q_n // BQ, c_n // BC),
         in_specs=[pl.BlockSpec((BQ, d), lambda i, j, nv: (i, 0)),
-                  pl.BlockSpec((BQ,), lambda i, j, nv: (i,)),
+                  pl.BlockSpec((BQ, 1), lambda i, j, nv: (i, 0)),
                   pl.BlockSpec((BC, d), lambda i, j, nv: (j, 0)),
-                  pl.BlockSpec((BC,), lambda i, j, nv: (j,))],
+                  pl.BlockSpec((1, BC), lambda i, j, nv: (0, j))],
         out_specs=[pl.BlockSpec((BQ, k), lambda i, j, nv: (i, 0)),
                    pl.BlockSpec((BQ, k), lambda i, j, nv: (i, 0))])
     return pl.pallas_call(
@@ -222,4 +245,4 @@ def sim_topk_q8_pallas(q8: jnp.ndarray, qscale: jnp.ndarray,
                    jax.ShapeDtypeStruct((q_n, k), jnp.int32)],
         interpret=interpret,
     )(jnp.asarray(n_valid, jnp.int32).reshape(1),
-      q8, qscale, c8, cscale)
+      q8, qscale.reshape(q_n, 1), c8, cscale.reshape(1, c_n))

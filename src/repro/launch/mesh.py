@@ -46,20 +46,11 @@ def make_cache_mesh(n_shards: int):
 
 
 def abstract_mesh(shape, axis_names):
-    """Version-portable ``jax.sharding.AbstractMesh`` construction.
-
-    The AbstractMesh calling convention differs across jax releases: some
-    take a single tuple of ``(name, size)`` pairs (e.g. 0.4.37, tried
-    first), others take ``(shape, axis_names)`` as two positional tuples
-    (the fallback).  Every analysis
-    path (sharding-plan rules, HLO cost tests) builds device-free meshes
-    through this helper so the repo tracks either convention.
-    """
+    """A device-free ``jax.sharding.AbstractMesh`` of ``shape`` with
+    ``axis_names`` — what every analysis path (sharding-plan rules, HLO
+    cost tests) builds its meshes from."""
     from jax.sharding import AbstractMesh
     shape = tuple(int(s) for s in shape)
     axis_names = tuple(axis_names)
     assert len(shape) == len(axis_names)
-    try:
-        return AbstractMesh(tuple(zip(axis_names, shape)))
-    except TypeError:
-        return AbstractMesh(shape, axis_names)
+    return AbstractMesh(shape, axis_names)
