@@ -636,9 +636,12 @@ class KernelBackend:
 
     @property
     def dispatch_stats(self) -> dict:
-        """Launch/transfer observability: jitted dispatches issued, blocking
-        device→host syncs, and seconds spent inside timed kernel intervals.
-        Process-global (the jit caches are too) — consumers read deltas."""
+        """Launch/transfer observability (``ops.dispatch_stats``): jitted
+        dispatches issued, blocking device→host syncs, host wall seconds
+        from dispatch to ready (``kernel_s``: uploads of host arguments
+        included, not kernel time), and the explicit host→device slab
+        uploads (``upload_s``, ``uploads``).  Process-global (the jit
+        caches are too) — consumers read deltas."""
         from repro.kernels import ops
         return dict(ops.dispatch_stats)
 
@@ -670,15 +673,18 @@ class KernelBackend:
                     np.full(b, -np.inf, dtype=np.float64))
         pad = (-b) % self.q_pad
         qp = np.pad(queries, ((0, pad), (0, 0))) if pad else queries
+        # the slab goes to the device in a span of its own, ended when the
+        # transfer is done, so a trace tells the upload from the scan
+        with annotate("rac/upload"):
+            slab = ops.upload(store.emb)
         # runtime n_valid = the store's high-water mark: slots past it have
         # never been occupied, so the kernel skips scoring the free tail
         # (one compilation — the count is scalar-prefetched, not baked in)
         with annotate("rac/sim_top1"):
             vals, idx = ops.run_timed(
-                lambda: ops.sim_top1(qp, store.emb, n_valid=store.hwm,
+                lambda: ops.sim_top1(qp, slab, n_valid=store.hwm,
                                      use_pallas=self.use_pallas,
-                                     interpret=self.interpret),
-                self._tracker, "sim_top1")
+                                     interpret=self.interpret))
         vals = np.asarray(ops.to_host(vals)[:b], dtype=np.float64)
         idx = ops.to_host(idx)[:b]
         cids = store.cid[idx].copy()
@@ -713,8 +719,7 @@ class KernelBackend:
                 lambda: ops.sim_topk_q8(q8, qs, dev["q8"], dev["scale"], k,
                                         n_valid=store.hwm,
                                         use_pallas=self.use_pallas,
-                                        interpret=self.interpret),
-                self._tracker, "sim_topk_q8")
+                                        interpret=self.interpret))
         vals = np.asarray(ops.to_host(vals)[:b], dtype=np.float64)
         rows = ops.to_host(idx)[:b]
         eps = scan_margin(qs[:b], ql1[:b], qm.scale, qm.l1, dim)
@@ -771,8 +776,7 @@ class KernelBackend:
                     lambda: ops.route_topics(
                         qp, dev["aug"], cfg.probes, n_valid=n_top,
                         use_pallas=self.use_pallas,
-                        interpret=self.interpret),
-                    self._tracker, "route_topics")
+                        interpret=self.interpret))
             return ops.to_host(vals)[:b], ops.to_host(tids)[:b]
 
         if self.quantized is not None:
@@ -817,8 +821,7 @@ class KernelBackend:
                     qp, q8q, qsc, ql1, slab["emb"], dev["q8"],
                     dev["scale"], dev["l1"], store.hwm, b, cfg.tau_hit,
                     k=min(int(cfg.k), n_slots), use_pallas=self.use_pallas,
-                    interpret=self.interpret),
-                self._tracker, "fused_quant")
+                    interpret=self.interpret))
         win, rmax, cert, n_u = ops.to_host_tuple(out)
         win = win[:b].astype(np.int64)
         rmax = np.asarray(rmax[:b], dtype=np.float64)
@@ -901,8 +904,7 @@ class KernelBackend:
                     q8_dev["scale"], q8_dev["l1"], aug_dev, csr["indptr"],
                     csr["slots"], int(table.topic_hwm), budget, b,
                     cfg.tau_hit, probes=probes, cap_c=cap_c, k=k,
-                    use_pallas=self.use_pallas, interpret=self.interpret),
-                self._tracker, "fused_pruned")
+                    use_pallas=self.use_pallas, interpret=self.interpret))
         win, rmax, ub, cert, total, probed, capped, n_u = \
             ops.to_host_tuple(out)
         local = win[:b].astype(np.int64) - slot_off
@@ -966,8 +968,7 @@ class KernelBackend:
                 vals, idx = ops.run_timed(
                     lambda: ops.sim_topk_q8(q8, qsc, c8, csc, k, n_valid=n,
                                             use_pallas=self.use_pallas,
-                                            interpret=self.interpret),
-                    self._tracker, "sim_topk_q8")
+                                            interpret=self.interpret))
             vals = np.asarray(ops.to_host(vals)[:b], dtype=np.float64)
             lrows = ops.to_host(idx)[:b]
             eps = scan_margin(qsc[:b], ql1[:b], qm.scale[rows],
@@ -1033,8 +1034,7 @@ class KernelBackend:
                     lambda: ops.route_topics(
                         qp, aug, cfg.probes, n_valid=n_top,
                         use_pallas=self.use_pallas,
-                        interpret=self.interpret),
-                    self._tracker, "route_topics")
+                        interpret=self.interpret))
             return ops.to_host(vals)[:bq], ops.to_host(tids)[:bq]
 
         out_c = np.full((n_pol, b), -1, dtype=np.int64)
@@ -1169,8 +1169,7 @@ class KernelBackend:
                 lambda: ops.sim_top1_multi(
                     qp, dev["emb"].reshape(n_pol, n_slots, dim),
                     n_valid=arena.hwms(), use_pallas=self.use_pallas,
-                    interpret=self.interpret),
-                self._tracker, "sim_top1_multi")
+                    interpret=self.interpret))
         vals = np.asarray(ops.to_host(vals)[:, :b], dtype=np.float64)
         idx = ops.to_host(idx)[:, :b]
         cids = arena.cid[np.arange(n_pol)[:, None], idx].copy()
@@ -1208,8 +1207,7 @@ class KernelBackend:
                 lambda: ops.sim_topk_q8_multi(
                     q8, qs, dev["q8"].reshape(n_pol, n_slots, dim),
                     dev["scale"].reshape(n_pol, n_slots), k, n_valid=hwms,
-                    use_pallas=self.use_pallas, interpret=self.interpret),
-                self._tracker, "sim_topk_q8_multi")
+                    use_pallas=self.use_pallas, interpret=self.interpret))
         vals = np.asarray(ops.to_host(vals)[:, :b], dtype=np.float64)
         rows = ops.to_host(idx)[:, :b]
         scale2 = qm.scale.reshape(n_pol, n_slots)
@@ -1239,27 +1237,29 @@ class KernelBackend:
     def rac_value_masked(self, tsi, tids, tp_last, t_last, alpha, t_now,
                          valid):
         from repro.kernels import ops
-        out = ops.rac_value_masked(
-            np.asarray(tsi, dtype=np.float32),
-            np.asarray(tids, dtype=np.int32),
-            np.asarray(tp_last, dtype=np.float32),
-            np.asarray(t_last - t_now, dtype=np.int32),
-            np.asarray(valid, dtype=bool), float(alpha), 0,
-            use_pallas=self.use_pallas, interpret=self.interpret)
-        return np.asarray(ops.to_host(out), dtype=np.float64)
+        with annotate("rac/rac_value"):
+            out = ops.rac_value_masked(
+                np.asarray(tsi, dtype=np.float32),
+                np.asarray(tids, dtype=np.int32),
+                np.asarray(tp_last, dtype=np.float32),
+                np.asarray(t_last - t_now, dtype=np.int32),
+                np.asarray(valid, dtype=bool), float(alpha), 0,
+                use_pallas=self.use_pallas, interpret=self.interpret)
+            return np.asarray(ops.to_host(out), dtype=np.float64)
 
     def rac_value(self, tsi, tids, tp_last, t_last, alpha, t_now):
         from repro.kernels import ops
         # shift timestamps so t_now is the static constant 0: the kernel
         # sees 0 - (t_last - t_now) = t_now - t_last, and jit never
         # recompiles as simulation time advances.
-        out = ops.rac_value(np.asarray(tsi, dtype=np.float32),
-                            np.asarray(tids, dtype=np.int32),
-                            np.asarray(tp_last, dtype=np.float32),
-                            np.asarray(t_last - t_now, dtype=np.int32),
-                            float(alpha), 0, use_pallas=self.use_pallas,
-                            interpret=self.interpret)
-        return np.asarray(ops.to_host(out), dtype=np.float64)
+        with annotate("rac/rac_value"):
+            out = ops.rac_value(np.asarray(tsi, dtype=np.float32),
+                                np.asarray(tids, dtype=np.int32),
+                                np.asarray(tp_last, dtype=np.float32),
+                                np.asarray(t_last - t_now, dtype=np.int32),
+                                float(alpha), 0, use_pallas=self.use_pallas,
+                                interpret=self.interpret)
+            return np.asarray(ops.to_host(out), dtype=np.float64)
 
     def _device_state(self, store: ResidentStore, table: PolicyTable) -> dict:
         """The mirrored decision state, freshened by dirty-row scatter."""
@@ -1300,8 +1300,7 @@ class KernelBackend:
                     qp, dev["emb"], store.hwm, dev["rep"], table.topic_hwm,
                     dev["tsi"], dev["tid"], dev["occ"], dev["tp"],
                     dev["tl"], t_now, alpha=float(alpha),
-                    use_pallas=self.use_pallas, interpret=self.interpret),
-                self._tracker, "fused_decide")
+                    use_pallas=self.use_pallas, interpret=self.interpret))
         hv, hi, rv, ri, vv = ops.to_host_tuple(out)
         hv = np.asarray(hv[:b], dtype=np.float64)
         cids = store.cid[np.asarray(hi[:b])].copy()
@@ -1343,8 +1342,7 @@ class KernelBackend:
                     qp, topic["rep"], table.topic_hwm, slot["tsi"],
                     slot["tid"], np.asarray(store.occ, dtype=np.int32),
                     topic["tp"], topic["tl"], t_now, alpha=float(alpha),
-                    use_pallas=self.use_pallas, interpret=self.interpret),
-                self._tracker, "decide_aux")
+                    use_pallas=self.use_pallas, interpret=self.interpret))
         rv, ri, vv = ops.to_host_tuple(out)
         rv = np.asarray(rv[:b], dtype=np.float64)
         ri = np.where(np.isfinite(rv),

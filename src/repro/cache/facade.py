@@ -53,6 +53,7 @@ import numpy as np
 
 from repro.core.store import ResidentStore
 from repro.core.types import Request
+from repro.telemetry.tracing import annotate
 from repro.telemetry.tracker import make_tracker
 
 from .backends import LookupBackend, get_backend
@@ -279,7 +280,8 @@ class SemanticCache:
             # report zeros (they never dispatch)
             dispatch = getattr(self.backend, "dispatch_stats", None)
             if dispatch is None:
-                dispatch = {"launches": 0, "host_syncs": 0, "kernel_s": 0.0}
+                dispatch = {"launches": 0, "host_syncs": 0, "kernel_s": 0.0,
+                            "upload_s": 0.0, "uploads": 0}
             snap["dispatch"] = dict(dispatch)
             return snap
 
@@ -481,19 +483,20 @@ class SemanticCache:
         embs = np.asarray(embs, dtype=np.float32)
         b = embs.shape[0]
         cids = list(cids) if cids is not None else [-1] * b
-        if self.cfg.hit_mode == "content":
+        with annotate("rac/lookup_batch"):
+            if self.cfg.hit_mode == "content":
+                return [self.lookup(embs[i], cid=cids[i],
+                                    t=None if ts is None else ts[i],
+                                    req=None if reqs is None else reqs[i])
+                        for i in range(b)]
+            t0 = time.perf_counter()
+            top_cids, top_sims = self.peek_batch(embs)
+            self.metrics.lookup_s += time.perf_counter() - t0
             return [self.lookup(embs[i], cid=cids[i],
                                 t=None if ts is None else ts[i],
-                                req=None if reqs is None else reqs[i])
+                                req=None if reqs is None else reqs[i],
+                                top1=(int(top_cids[i]), float(top_sims[i])))
                     for i in range(b)]
-        t0 = time.perf_counter()
-        top_cids, top_sims = self.peek_batch(embs)
-        self.metrics.lookup_s += time.perf_counter() - t0
-        return [self.lookup(embs[i], cid=cids[i],
-                            t=None if ts is None else ts[i],
-                            req=None if reqs is None else reqs[i],
-                            top1=(int(top_cids[i]), float(top_sims[i])))
-                for i in range(b)]
 
     # ------------------------------------------------------------- admit
     def admit(self, cid: int, emb: np.ndarray, payload: Any = None, *,
@@ -535,7 +538,7 @@ class SemanticCache:
         drain target)."""
         t0 = time.perf_counter()
         evicted: list[int] = []
-        with self._lock:
+        with annotate("rac/admit"), self._lock:
             t = self._tick(t)
             if self.cfg.capacity <= 0:
                 # nothing can ever be inserted: storing the payload would
@@ -553,12 +556,16 @@ class SemanticCache:
                 # the policy BEFORE on_admit, so the normal arrival path
                 # restores the preserved counters
                 self.tiers.on_admit(cid, self.policy, emb)
-            self.policy.on_admit(cid, self._request(cid, emb, t, req), t)
+            with annotate("rac/arrive"):
+                self.policy.on_admit(cid, self._request(cid, emb, t, req), t)
             self.metrics.admissions += 1
             self._emit("admit", cid, t, payload=payload)
             trk = self._trk
             while len(self.store) > self.cfg.capacity:
-                victim = self.policy.victim(t)
+                tv = time.perf_counter()
+                with annotate("rac/victim"):
+                    victim = self.policy.victim(t)
+                self.metrics.victim_s += time.perf_counter() - tv
                 vemb = (self.store.emb[self.store.slot_of[victim]].copy()
                         if self.tiers is not None else None)
                 self.store.remove(victim)

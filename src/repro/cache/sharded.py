@@ -187,9 +187,12 @@ class ShardedKernelBackend:
 
     @property
     def dispatch_stats(self) -> dict:
-        """Launch/transfer observability: jitted dispatches issued, blocking
-        device→host syncs, and seconds spent inside timed kernel intervals.
-        Process-global (the jit caches are too) — consumers read deltas."""
+        """Launch/transfer observability (``ops.dispatch_stats``): jitted
+        dispatches issued, blocking device→host syncs, host wall seconds
+        from dispatch to ready (``kernel_s``: uploads of host arguments
+        included, not kernel time), and the explicit host→device slab
+        uploads (``upload_s``, ``uploads``).  Process-global (the jit
+        caches are too) — consumers read deltas."""
         from repro.kernels import ops
         return dict(ops.dispatch_stats)
 

@@ -203,6 +203,7 @@ class CacheMetrics:
     lookups: int = 0
     lookup_s: float = 0.0
     admit_s: float = 0.0
+    victim_s: float = 0.0                # inside policy.victim (in admit_s)
     hook_errors: int = 0                 # subscriber hooks that raised
 
     @property
@@ -219,5 +220,5 @@ class CacheMetrics:
             "admissions": self.admissions, "evictions": self.evictions,
             "lookups": self.lookups, "hit_ratio": self.hit_ratio,
             "lookup_s": self.lookup_s, "admit_s": self.admit_s,
-            "hook_errors": self.hook_errors,
+            "victim_s": self.victim_s, "hook_errors": self.hook_errors,
         }

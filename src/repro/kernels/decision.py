@@ -65,6 +65,7 @@ def victim_value_pallas(tsi: jnp.ndarray, tid: jnp.ndarray,
     out = pl.pallas_call(
         functools.partial(_victim_value_kernel, alpha=alpha),
         grid_spec=grid_spec,
+        name="victim_value",
         out_shape=jax.ShapeDtypeStruct((n // LANES, LANES), jnp.float32),
         interpret=interpret,
     )(jnp.asarray(t_now, jnp.int32).reshape(1),

@@ -31,10 +31,13 @@ def _is_cpu() -> bool:
 
 #: Process-global dispatch ledger.  ``launches`` counts kernel dispatches
 #: (one per public wrapper call — each is one jitted program), ``host_syncs``
-#: counts device→host materializations (every ``to_host``), and ``kernel_s``
-#: accumulates blocked-on-device wall time from ``run_timed`` so benches can
-#: separate scan time from host-driver overhead.
-dispatch_stats = {"launches": 0, "host_syncs": 0, "kernel_s": 0.0}
+#: counts device→host materializations (every ``to_host``), ``kernel_s``
+#: accumulates the host wall time of ``run_timed`` intervals — dispatch to
+#: ready, so it includes the upload of any host array passed in and is not
+#: kernel time — and ``upload_s``/``uploads`` time and count the explicit
+#: host→device transfers made through :func:`upload`.
+dispatch_stats = {"launches": 0, "host_syncs": 0, "kernel_s": 0.0,
+                  "upload_s": 0.0, "uploads": 0}
 
 
 def count_launch(n: int = 1) -> None:
@@ -57,19 +60,27 @@ def to_host_tuple(xs):
     return jax.device_get(xs)
 
 
-def run_timed(fn, tracker=None, name: str = "kernel"):
+def upload(x):
+    """Hand the host array ``x`` to the default device and wait until the
+    transfer is done, charging the interval to ``dispatch_stats["upload_s"]``
+    (and one to ``uploads``), so the transfer is timed apart from the
+    kernel that reads it."""
+    t0 = time.perf_counter()
+    out = jax.block_until_ready(jax.device_put(x))
+    dispatch_stats["upload_s"] += time.perf_counter() - t0
+    dispatch_stats["uploads"] += 1
+    return out
+
+
+def run_timed(fn):
     """Run ``fn`` (a zero-arg closure dispatching device work), block until
     its outputs are ready, and charge the interval to
-    ``dispatch_stats["kernel_s"]`` — the kernel-time clock the roofline
-    table reads alongside wall-clock.  When a tracker is attached the
-    interval is also emitted as a trace span."""
+    ``dispatch_stats["kernel_s"]``: host wall time from dispatch to ready,
+    uploads of host arguments included."""
     t0 = time.perf_counter()
     out = fn()
     jax.block_until_ready(out)
-    t1 = time.perf_counter()
-    dispatch_stats["kernel_s"] += t1 - t0
-    if tracker is not None:
-        tracker.add_span(f"kernel/{name}", t0, t1)
+    dispatch_stats["kernel_s"] += time.perf_counter() - t0
     return out
 
 

@@ -38,6 +38,7 @@ def rac_value_pallas(tsi: jnp.ndarray, tid: jnp.ndarray,
     out = pl.pallas_call(
         functools.partial(_rac_value_kernel, alpha=alpha, t_now=t_now),
         grid=(n // BN,),
+        name="rac_value",
         in_specs=[spec] * len(cols),
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((n // LANES, LANES), jnp.float32),

@@ -103,6 +103,7 @@ def sim_top1_pallas(queries: jnp.ndarray, candidates: jnp.ndarray,
     vals, idx = pl.pallas_call(
         _sim_top1_kernel,
         grid_spec=grid_spec,
+        name="sim_top1",
         out_shape=[jax.ShapeDtypeStruct((q_n, 1), jnp.float32),
                    jax.ShapeDtypeStruct((q_n, 1), jnp.int32)],
         interpret=interpret,
@@ -207,6 +208,7 @@ def sim_topk_pallas(queries: jnp.ndarray, candidates: jnp.ndarray,
     return pl.pallas_call(
         _make_sim_topk_kernel(k),
         grid_spec=grid_spec,
+        name="sim_topk",
         out_shape=[jax.ShapeDtypeStruct((q_n, k), jnp.float32),
                    jax.ShapeDtypeStruct((q_n, k), jnp.int32)],
         interpret=interpret,
@@ -241,6 +243,7 @@ def sim_topk_q8_pallas(q8: jnp.ndarray, qscale: jnp.ndarray,
     return pl.pallas_call(
         _make_sim_topk_q8_kernel(k),
         grid_spec=grid_spec,
+        name="sim_topk_q8",
         out_shape=[jax.ShapeDtypeStruct((q_n, k), jnp.float32),
                    jax.ShapeDtypeStruct((q_n, k), jnp.int32)],
         interpret=interpret,

@@ -41,6 +41,7 @@ import numpy as np
 from repro.cache import CacheConfig, SemanticCache, TierConfig
 from repro.models import Model, build_model, make_decode_step
 from repro.models.config import ModelConfig
+from repro.telemetry.tracing import annotate
 from repro.telemetry.tracker import make_tracker
 
 
@@ -196,7 +197,8 @@ class ServingEngine:
         done: list[RequestState] = []
         slots: list[Optional[RequestState]] = [None] * ecfg.max_batch
 
-        cache = self.model.init_cache(ecfg.max_batch, ecfg.max_seq)
+        with annotate("rac/engine/init_kv"):
+            cache = self.model.init_cache(ecfg.max_batch, ecfg.max_seq)
         pos = np.zeros(ecfg.max_batch, np.int32)
         cur = np.zeros(ecfg.max_batch, np.int32)
         budget = np.zeros(ecfg.max_batch, np.int32)
@@ -231,96 +233,104 @@ class ServingEngine:
             queue[:] = waiting
 
         def try_fill():
-            # batch boundary: settle queued admissions before any hit
-            # determination, so async and synchronous admission see the
-            # same store state at every lookup (identical outputs)
-            if queue:
-                self.cache.flush()
-            # batched hit determination: the full queue is scored in ONE
-            # fused decide_batch launch at first entry (hit Top-1 through
-            # the policy's device-mirrored PolicyTable state); afterwards
-            # each waiting request only scores against entries admitted
-            # since the last pass (O(queue x new-admits), not O(queue x
-            # store)), keeping its running best-known top-1 in `peeked`.
-            # A stale best whose entry was evicted is caught by residency
-            # checks here and by lookup()'s revalidation at scheduling time.
-            if queue and not peeked_once[0]:
-                peeked_once[0] = True
-                dec = self.cache.decide_batch(
-                    np.stack([r.emb for r in queue]))
-                for req, c, s in zip(queue, dec.hit_cid, dec.hit_sim):
-                    peeked[req.rid] = (int(c), float(s))
-                if dec.host_cid is not None:
-                    # tiered: a host-resident entry can out-score every
-                    # device row; drain_hits serves it through lookup(),
-                    # which falls through to the host tier and promotes
-                    for req, c, s in zip(queue, dec.host_cid, dec.host_sim):
-                        if float(s) > peeked[req.rid][1]:
-                            peeked[req.rid] = (int(c), float(s))
-                recent.clear()
-                drain_hits()
-            elif queue and recent:
-                # row-restricted peek THROUGH the backend: the rescan uses
-                # the same cosine scoring as the full peek, so peeked sims
-                # and backend sims cannot disagree near tau_hit
-                fresh = list(dict.fromkeys(recent))
-                recent.clear()
-                cids, sims = self.cache.peek_rows(
-                    np.stack([r.emb for r in queue]), fresh)
-                for i, req in enumerate(queue):
-                    if sims[i] > peeked[req.rid][1]:
-                        peeked[req.rid] = (int(cids[i]), float(sims[i]))
-                drain_hits()
-            while queue:
-                free = [i for i, s in enumerate(slots) if s is None]
-                if not free:
-                    return
-                i = free[0]
-                req = queue.pop(0)
-                res = self.cache.lookup(req.emb, cid=req.cid,
-                                        top1=peeked.get(req.rid))
-                if res.hit:          # store unchanged since peek: rare race
-                    serve_hit(req, res)
-                    continue
-                slots[i] = req
-                req.t_sched = time.perf_counter()
-                # (prefill folded into decode slots for simplicity: prompt
-                # tokens are fed one per step — fine at smoke scale)
-                req._feed = list(req.tokens)
-                pos[i] = 0
-                cur[i] = req._feed.pop(0)
-                budget[i] = ecfg.max_new_tokens
+            with annotate("rac/engine/fill"):
+                # batch boundary: settle queued admissions before any hit
+                # determination, so async and synchronous admission see the
+                # same store state at every lookup (identical outputs)
+                if queue:
+                    self.cache.flush()
+                # batched hit determination: the full queue is scored in ONE
+                # fused decide_batch launch at first entry (hit Top-1 through
+                # the policy's device-mirrored PolicyTable state); afterwards
+                # each waiting request only scores against entries admitted
+                # since the last pass (O(queue x new-admits), not O(queue x
+                # store)), keeping its running best-known top-1 in `peeked`.
+                # A stale best whose entry was evicted is caught by
+                # residency checks here and by lookup()'s revalidation at
+                # scheduling time.
+                if queue and not peeked_once[0]:
+                    peeked_once[0] = True
+                    dec = self.cache.decide_batch(
+                        np.stack([r.emb for r in queue]))
+                    for req, c, s in zip(queue, dec.hit_cid, dec.hit_sim):
+                        peeked[req.rid] = (int(c), float(s))
+                    if dec.host_cid is not None:
+                        # tiered: a host-resident entry can out-score every
+                        # device row; drain_hits serves it through lookup(),
+                        # which falls through to the host tier and promotes
+                        for req, c, s in zip(queue, dec.host_cid,
+                                             dec.host_sim):
+                            if float(s) > peeked[req.rid][1]:
+                                peeked[req.rid] = (int(c), float(s))
+                    recent.clear()
+                    drain_hits()
+                elif queue and recent:
+                    # row-restricted peek THROUGH the backend: the rescan uses
+                    # the same cosine scoring as the full peek, so peeked sims
+                    # and backend sims cannot disagree near tau_hit
+                    fresh = list(dict.fromkeys(recent))
+                    recent.clear()
+                    cids, sims = self.cache.peek_rows(
+                        np.stack([r.emb for r in queue]), fresh)
+                    for i, req in enumerate(queue):
+                        if sims[i] > peeked[req.rid][1]:
+                            peeked[req.rid] = (int(cids[i]), float(sims[i]))
+                    drain_hits()
+                while queue:
+                    free = [i for i, s in enumerate(slots) if s is None]
+                    if not free:
+                        return
+                    i = free[0]
+                    req = queue.pop(0)
+                    res = self.cache.lookup(req.emb, cid=req.cid,
+                                            top1=peeked.get(req.rid))
+                    if res.hit:      # store unchanged since peek: rare race
+                        serve_hit(req, res)
+                        continue
+                    slots[i] = req
+                    req.t_sched = time.perf_counter()
+                    # (prefill folded into decode slots for simplicity: prompt
+                    # tokens are fed one per step — fine at smoke scale)
+                    req._feed = list(req.tokens)
+                    pos[i] = 0
+                    cur[i] = req._feed.pop(0)
+                    budget[i] = ecfg.max_new_tokens
 
+        # one profiler span per phase of a decode step (never per slot), so
+        # a device trace can charge each idle gap to the host work in it
         try_fill()
         while any(s is not None for s in slots):
-            batch = {"tokens": jnp.asarray(cur[:, None]),
-                     "pos": jnp.asarray(pos)}
-            nxt, _, cache = self.decode(self.params, cache, batch)
-            nxt = np.asarray(nxt)
+            with annotate("rac/engine/dispatch"):
+                batch = {"tokens": jnp.asarray(cur[:, None]),
+                         "pos": jnp.asarray(pos)}
+                nxt, _, cache = self.decode(self.params, cache, batch)
+            with annotate("rac/engine/sync"):
+                nxt = np.asarray(nxt)
             self._gen["batches"] += 1
-            for i, s in enumerate(slots):
-                if s is None:
-                    continue
-                pos[i] += 1
-                if s._feed:                      # still consuming the prompt
-                    cur[i] = s._feed.pop(0)
-                    continue
-                tok = int(nxt[i])
-                if not s.out_tokens:
-                    s.t_first = time.perf_counter()
-                s.out_tokens.append(tok)
-                self._gen["generated_tokens"] += 1
-                budget[i] -= 1
-                if budget[i] <= 0 or pos[i] >= ecfg.max_seq - 1:
-                    s.done = True
-                    s.t_done = time.perf_counter()
-                    self.cache.admit(s.cid, s.emb,
-                                     payload=list(s.out_tokens))
-                    self._finish(s, "generated")
-                    done.append(s)
-                    slots[i] = None
-                else:
-                    cur[i] = tok
+            with annotate("rac/engine/slots"):
+                for i, s in enumerate(slots):
+                    if s is None:
+                        continue
+                    pos[i] += 1
+                    if s._feed:                  # still consuming the prompt
+                        cur[i] = s._feed.pop(0)
+                        continue
+                    tok = int(nxt[i])
+                    if not s.out_tokens:
+                        s.t_first = time.perf_counter()
+                    s.out_tokens.append(tok)
+                    self._gen["generated_tokens"] += 1
+                    budget[i] -= 1
+                    if budget[i] <= 0 or pos[i] >= ecfg.max_seq - 1:
+                        s.done = True
+                        s.t_done = time.perf_counter()
+                        self.cache.admit(s.cid, s.emb,
+                                         payload=list(s.out_tokens))
+                        self._finish(s, "generated")
+                        done.append(s)
+                        slots[i] = None
+                    else:
+                        cur[i] = tok
             try_fill()
         self.cache.flush()           # settle admissions queued in the tail
         return sorted(done, key=lambda r: r.rid)

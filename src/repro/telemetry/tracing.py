@@ -9,11 +9,16 @@ itself).  ``to_chrome()`` renders the buffer as Chrome trace-event JSON
 serving run's request lifecycle — arrive → hit / queue → fill → complete
 — loads straight into a trace viewer.
 
-``annotate(name)`` is the kernel-launch passthrough: it returns a
-``jax.profiler.TraceAnnotation`` when jax is importable (the span then
-shows up inside XLA device traces captured with ``jax.profiler.trace``)
-and a no-op context otherwise, so host-only consumers never pay a jax
-import.  Device backends wrap their fused launches in it.
+``annotate(name)`` writes a host span into the JAX profiler's own trace
+(a ``jax.profiler.TraceAnnotation``), which shares the device trace's
+clock: a span there lies beside the device ops it launched or left the
+chip idle for.  It is a no-op context until jax is loaded, so host-only
+consumers never pay a jax import.  The program's hot path is marked with
+``rac/...`` annotations (see ``docs/observability.md``), and
+``TraceBuffer.span`` enters one too, so a live span recorded for a Chrome
+export also lies on the profiler's timeline.  Retroactive ``add_span``
+records (times the caller already holds) stay host-only: the profiler
+cannot take a span after the fact.
 
 Trace ids are process-monotonic ints from :func:`next_trace_id` —
 decisions must never depend on telemetry, so ids come from a counter,
@@ -24,6 +29,7 @@ from __future__ import annotations
 import contextlib
 import itertools
 import json
+import sys
 import threading
 import time
 from typing import Optional
@@ -31,6 +37,7 @@ from typing import Optional
 __all__ = ["TraceBuffer", "annotate", "next_trace_id"]
 
 _trace_ids = itertools.count(1)
+_NULL_SPAN = contextlib.nullcontext()       # reusable & reentrant
 
 
 def next_trace_id() -> int:
@@ -39,13 +46,13 @@ def next_trace_id() -> int:
 
 
 def annotate(name: str):
-    """``jax.profiler.TraceAnnotation`` passthrough around kernel launches;
-    degrades to a no-op context when jax is unavailable."""
-    try:
-        import jax
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return contextlib.nullcontext()
+    """A host span ``name`` in the JAX profiler's trace (one clock with the
+    device's); a no-op context when jax is not loaded, since a process
+    that never imported jax has no profiler trace to write into."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return _NULL_SPAN
+    return jax.profiler.TraceAnnotation(name)
 
 
 class TraceBuffer:
@@ -81,12 +88,15 @@ class TraceBuffer:
 
     @contextlib.contextmanager
     def span(self, name: str, *, track: int = 0, tags: Optional[dict] = None):
-        t0 = time.perf_counter()
-        try:
-            yield self
-        finally:
-            self.add_span(name, t0, time.perf_counter(), track=track,
-                          tags=tags)
+        """Time the enclosed block into the buffer; the block is also an
+        :func:`annotate` span, so it shows in a profiler trace as well."""
+        with annotate(name):
+            t0 = time.perf_counter()
+            try:
+                yield self
+            finally:
+                self.add_span(name, t0, time.perf_counter(), track=track,
+                              tags=tags)
 
     # ------------------------------------------------------------- export
     def to_chrome(self) -> dict:

@@ -39,7 +39,7 @@ import time
 from typing import Any, Optional, Sequence
 
 from .metrics import MetricsRegistry
-from .tracing import TraceBuffer
+from .tracing import TraceBuffer, annotate
 
 __all__ = ["Tracker", "NoopTracker", "NOOP", "InMemoryTracker",
            "JsonlTracker", "CompositeTracker", "make_tracker"]
@@ -70,7 +70,10 @@ class Tracker:
         pass
 
     def span(self, name: str, tags: Optional[dict] = None):
-        """Context manager timing a scoped operation."""
+        """Context manager timing a scoped operation; the recording sinks
+        also enter :func:`~repro.telemetry.tracing.annotate`, so the span
+        lies on the JAX profiler's timeline too (``add_span`` records do
+        not: they are host-only)."""
         return _NULL_SPAN
 
     def add_span(self, name: str, t0: float, t1: float, *, track: int = 0,
@@ -260,11 +263,12 @@ class JsonlTracker(Tracker):
 
     @contextlib.contextmanager
     def _timed_span(self, name, tags):
-        t0 = time.perf_counter()
-        try:
-            yield self
-        finally:
-            self.add_span(name, t0, time.perf_counter(), tags=tags)
+        with annotate(name):                 # on the profiler's clock too
+            t0 = time.perf_counter()
+            try:
+                yield self
+            finally:
+                self.add_span(name, t0, time.perf_counter(), tags=tags)
 
     def span(self, name, tags=None):
         return self._timed_span(name, tags)

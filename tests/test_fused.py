@@ -211,7 +211,8 @@ def test_uncapped_budget_keeps_small_stores_whole():
 def test_dispatch_ledger_in_snapshot():
     ev, cache = _events("kernel", {"fused": True}, False, reqs=_workload(n=24))
     snap = cache.metrics_snapshot()
-    assert set(snap["dispatch"]) == {"launches", "host_syncs", "kernel_s"}
+    assert set(snap["dispatch"]) == {"launches", "host_syncs", "kernel_s",
+                                     "upload_s", "uploads"}
     assert snap["dispatch"]["launches"] > 0
     assert snap["dispatch"]["host_syncs"] > 0
     assert snap["dispatch"]["kernel_s"] >= 0.0
@@ -219,7 +220,7 @@ def test_dispatch_ledger_in_snapshot():
     _, host = _events("numpy", True, False, reqs=_workload(n=8))
     host_snap = host.metrics_snapshot()
     assert set(host_snap["dispatch"]) == {"launches", "host_syncs",
-                                          "kernel_s"}
+                                          "kernel_s", "upload_s", "uploads"}
 
 
 def test_fused_launch_count_per_lookup():

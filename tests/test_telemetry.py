@@ -301,3 +301,165 @@ def test_checkpoint_restore_shares_tracker():
     assert cache.tracker is trk            # never cloned by the deep copy
     cache.lookup(emb, cid=1)
     assert trk.percentiles("cache.lookup_s") is not None
+
+
+# ------------------------------------- spans on the profiler's clock
+SPAN_PREFIXES = ("rac/", "op/")
+
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under a CPU ``jax.profiler`` trace and read the host
+    spans back as ``chipbench/trace.load`` does: (start_ns, end_ns, name)
+    of the ``/host:CPU`` plane's events, in start order."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0           # host: the annotations only
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                spans += [(e.start_ns, e.start_ns + e.duration_ns, e.name)
+                          for e in line.events
+                          if e.name.startswith(SPAN_PREFIXES)]
+    return sorted(spans, key=lambda s: (s[0], -s[1]))
+
+
+def _named(spans, name):
+    return [(a, b) for a, b, n in spans if n == name]
+
+
+def _each_inside(spans, inner, outer):
+    """Every ``inner`` span lies inside some ``outer`` span (and there is
+    at least one ``inner``)."""
+    outs = _named(spans, outer)
+    ins = _named(spans, inner)
+    return bool(ins) and all(any(oa <= a and b <= ob for oa, ob in outs)
+                             for a, b in ins)
+
+
+def test_cache_spans_nest_on_the_profiler_clock(tmp_path):
+    rng = np.random.default_rng(3)
+    cache = SemanticCache(CacheConfig(capacity=8, dim=16, tau_hit=0.85,
+                                      backend="kernel",
+                                      backend_kwargs={"interpret": True}))
+    rows = rng.normal(size=(12, 16)).astype(np.float32)
+    queries = rng.normal(size=(3, 16)).astype(np.float32)
+
+    def work():
+        for i in range(12):                # 4 admissions past capacity
+            cache.admit(i, rows[i])
+        cache.lookup_batch(queries)
+
+    spans = _profiled(tmp_path, work)
+    names = {n for _, _, n in spans}
+    assert {"rac/admit", "rac/arrive", "rac/victim", "rac/rac_value",
+            "rac/lookup_batch", "rac/upload", "rac/sim_top1"} <= names
+    assert len(_named(spans, "rac/admit")) == 12
+    assert len(_named(spans, "rac/victim")) == 4
+    assert len(_named(spans, "rac/lookup_batch")) == 1     # not per query
+    assert _each_inside(spans, "rac/arrive", "rac/admit")
+    assert _each_inside(spans, "rac/victim", "rac/admit")
+    assert _each_inside(spans, "rac/rac_value", "rac/victim")
+    assert _each_inside(spans, "rac/upload", "rac/lookup_batch")
+    assert _each_inside(spans, "rac/sim_top1", "rac/lookup_batch")
+    # the upload is the scan's sibling, done before the scan starts
+    (ua, ub), = _named(spans, "rac/upload")
+    (sa, sb), = _named(spans, "rac/sim_top1")
+    assert ub <= sa
+    assert [n for a, _, n in spans if ua <= a < sb] == ["rac/upload",
+                                                        "rac/sim_top1"]
+
+
+def test_engine_spans_per_decode_step_are_bounded(tmp_path):
+    from repro.configs import get_config
+    from repro.core import EmbeddingSpace
+    from repro.models import smoke_variant
+    from repro.serving import EngineConfig, ServingEngine
+    slots = 4
+    eng = ServingEngine(smoke_variant(get_config("paper")), EngineConfig(
+        cache_capacity=4, max_new_tokens=3, max_batch=slots, max_seq=64))
+    space = EmbeddingSpace(dim=64, seed=21)
+    reqs = [(i, space.content_embedding(i, i).astype(np.float32), [2, 3, 4])
+            for i in range(9)]
+    eng.run(reqs[:1])                      # compile outside the trace
+    steps0 = eng.stats["batches"]
+
+    spans = _profiled(tmp_path, lambda: eng.run(reqs[1:]))
+    steps = eng.stats["batches"] - steps0
+    assert steps > 0 and eng.stats["evictions"] > 0
+    kinds = {n for _, _, n in spans if n.startswith("rac/engine/")}
+    assert kinds == {"rac/engine/init_kv", "rac/engine/dispatch",
+                     "rac/engine/sync", "rac/engine/slots",
+                     "rac/engine/fill"}
+    # one span per phase of a step, none per slot
+    assert len(_named(spans, "rac/engine/init_kv")) == 1
+    for phase in ("dispatch", "sync", "slots"):
+        assert len(_named(spans, f"rac/engine/{phase}")) == steps
+    assert len(_named(spans, "rac/engine/fill")) == steps + 1
+    # finished requests are admitted (and evict) inside the slot loop
+    assert _each_inside(spans, "rac/admit", "rac/engine/slots")
+    assert _each_inside(spans, "rac/victim", "rac/admit")
+
+
+def test_tracker_spans_show_in_the_profiler_trace(tmp_path):
+    mem = InMemoryTracker()
+    jl = JsonlTracker(str(tmp_path / "t.jsonl"))
+
+    def work():
+        with mem.span("op/memory"):
+            pass
+        with jl.span("op/jsonl"):
+            pass
+        mem.add_span("op/after_the_fact", 0.0, 1.0)     # host-only
+
+    spans = _profiled(tmp_path / "prof", work)
+    assert {n for _, _, n in spans} == {"op/memory", "op/jsonl"}
+    assert [e[0] for e in mem.trace.events] == ["op/memory",
+                                                "op/after_the_fact"]
+    jl.close()
+
+
+def test_victim_and_upload_counters(parity_trace):
+    """``victim_s`` and the dispatch ledger's ``upload_s``/``uploads``
+    advance on the kernel backend; on the host backend ``victim_s``
+    advances the same way and no upload is counted.  Decisions agree."""
+    from repro.kernels import ops
+    reqs = parity_trace.requests[:80]
+    queries = np.stack([r.emb for r in reqs[:5]])
+    out = {}
+    for backend in ("numpy", "kernel"):
+        cache = SemanticCache(CacheConfig(
+            capacity=24, dim=16, tau_hit=0.85, backend=backend,
+            use_pallas=False))
+        evicted = []
+        for r in reqs:
+            evicted += cache.admit(r.cid, r.emb, t=r.t)
+        before = cache.metrics_snapshot()
+        up0 = dict(ops.dispatch_stats)
+        res = cache.lookup_batch(queries)
+        snap = cache.metrics_snapshot()
+        assert snap["evictions"] == len(evicted) > 0
+        assert 0.0 < snap["victim_s"] <= snap["admit_s"]
+        assert {"upload_s", "uploads"} <= set(snap["dispatch"])
+        uploads = snap["dispatch"]["uploads"] - before["dispatch"]["uploads"]
+        upload_s = (snap["dispatch"]["upload_s"]
+                    - before["dispatch"]["upload_s"])
+        if backend == "kernel":
+            assert uploads == ops.dispatch_stats["uploads"] - up0["uploads"]
+            assert uploads == 1 and upload_s > 0.0   # one slab per batch
+        else:
+            assert (snap["dispatch"]["uploads"],
+                    snap["dispatch"]["upload_s"]) == (0, 0.0)
+        out[backend] = (evicted, [(r.hit, getattr(r, "cid", None))
+                                  for r in res])
+    assert out["numpy"] == out["kernel"]

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -57,10 +58,15 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, sharding, *shapes):
+def _compile(fn, sharding, *shapes, kernels):
+    """Compile ``fn`` and check that each Pallas call it must hold is there
+    under its stable ``name=``, which a device trace labels it by."""
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    for name in kernels:
+        assert re.search(rf"%{name}(\.\d+)? = [^\n]*custom_call_target="
+                         r'"tpu_custom_call"', text), name
     return text
 
 
@@ -71,38 +77,42 @@ F32, I32, I8 = jnp.float32, jnp.int32, jnp.int8
 def test_sim_top1_compiles(one_chip, q):
     # 3 query tiles: XLA tiles a 1-D f32[384] by 512, not by the block's 128
     _compile(lambda q, c, nv: sim_top1_pallas(q, c, nv, interpret=False),
-             one_chip, ((q, D), F32), ((N, D), F32), ((), I32))
+             one_chip, ((q, D), F32), ((N, D), F32), ((), I32),
+             kernels=["sim_top1"])
 
 
 def test_sim_topk_compiles(one_chip):
     _compile(lambda q, c, nv: sim_topk_pallas(q, c, nv, K, interpret=False),
-             one_chip, ((Q, D), F32), ((N, D), F32), ((), I32))
+             one_chip, ((Q, D), F32), ((N, D), F32), ((), I32),
+             kernels=["sim_topk"])
 
 
 def test_sim_topk_q8_compiles(one_chip):
     _compile(lambda q8, qs, c8, cs, nv: sim_topk_q8_pallas(
         q8, qs, c8, cs, nv, K, interpret=False),
         one_chip, ((Q, D), I8), ((Q,), F32), ((N, D), I8), ((N,), F32),
-        ((), I32))
+        ((), I32), kernels=["sim_topk_q8"])
 
 
 def test_victim_value_compiles(one_chip):
     _compile(lambda tsi, tid, occ, tp, tl, tn: victim_value_pallas(
         tsi, tid, occ, tp, tl, tn, 0.001, interpret=False),
         one_chip, ((N,), F32), ((N,), I32), ((N,), I32), ((T,), F32),
-        ((T,), I32), ((), I32))
+        ((T,), I32), ((), I32), kernels=["victim_value"])
 
 
 def test_rac_value_compiles(one_chip):
     _compile(lambda tsi, tid, tp, tl: rac_value_pallas(
         tsi, tid, tp, tl, 0.001, 0, interpret=False),
-        one_chip, ((N,), F32), ((N,), I32), ((T,), F32), ((T,), I32))
+        one_chip, ((N,), F32), ((N,), I32), ((T,), F32), ((T,), I32),
+        kernels=["rac_value"])
 
 
 def test_route_topics_compiles(one_chip):
     _compile(lambda q, aug, nv: ops.route_topics_raw(
         q, aug, nv, 3, use_pallas=True, interpret=False),
-        one_chip, ((16, D), F32), ((T, D + 1), F32), ((), I32))
+        one_chip, ((16, D), F32), ((T, D + 1), F32), ((), I32),
+        kernels=["sim_topk"])
 
 
 def test_fused_quant_body_compiles(one_chip):
@@ -112,7 +122,8 @@ def test_fused_quant_body_compiles(one_chip):
     _compile(body, one_chip,
              ((b, D), F32), ((b, D), I8), ((b,), F32), ((b,), F32),
              ((N, D), F32), ((N, D), I8), ((N,), F32), ((N,), F32),
-             ((), I32), ((), I32), ((), F32))
+             ((), I32), ((), I32), ((), F32),
+             kernels=["sim_topk_q8", "sim_top1"])
 
 
 def test_fused_pruned_body_compiles(one_chip):
@@ -124,4 +135,5 @@ def test_fused_pruned_body_compiles(one_chip):
              ((b, D), F32), ((b, D), I8), ((b,), F32), ((b,), F32),
              ((N, D), F32), ((N, D), I8), ((N,), F32), ((N,), F32),
              ((T, D + 1), F32), ((T + 2,), I32), ((N,), I32),
-             ((), I32), ((), I32), ((), I32), ((), F32))
+             ((), I32), ((), I32), ((), I32), ((), F32),
+             kernels=["sim_topk", "sim_top1"])
