@@ -147,13 +147,16 @@ def _decode_sdpa(q, k, v, valid_mask, scale: Optional[float] = None):
 
 def attention_apply(p: dict, cfg: ModelConfig, x: jnp.ndarray,
                     positions: jnp.ndarray, *, causal: bool = True,
-                    window: int = 0, kv_cache=None, cache_positions=None,
-                    decode_mask=None, use_rope: bool = True,
+                    window: int = 0, kv_cache=None, decode_mask=None,
+                    use_rope: bool = True,
                     xattn_kv: Optional[jnp.ndarray] = None):
     """GQA attention.  Modes:
        - self-attn train/prefill: kv_cache None; on-the-fly banded mask
-       - decode: kv_cache = dict(k=(B,T,Hkv,D), v=...), x is (B,1,d),
-         decode_mask (B,T) marks valid cache slots
+       - decode: x is (B,1,d); kv_cache is the layer's cache writer: it
+         takes the step's rows dict(k=(B,Hkv,D), v=...), writes them at
+         each slot's position and returns the layer's cache dict(k=(B,T,
+         Hkv,D), v=...) after the write; decode_mask (B,T) marks valid
+         cache slots
        - cross-attn: xattn_kv = encoder states (no rope, no cache logic)
     """
     cd = cfg.cdtype
@@ -173,21 +176,17 @@ def attention_apply(p: dict, cfg: ModelConfig, x: jnp.ndarray,
         cos, sin = rope_cos_sin(positions, cfg.hd, cfg.rope_theta)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)     # decode: positions is (B,1) = current
-    new_cache = None
     if kv_cache is not None:
-        # functional single-position cache update (decode)
-        idx = cache_positions                      # (B,) int32 write index
-        bidx = jnp.arange(k.shape[0])
-        k_all = kv_cache["k"].at[bidx, idx].set(k[:, 0].astype(kv_cache["k"].dtype))
-        v_all = kv_cache["v"].at[bidx, idx].set(v[:, 0].astype(kv_cache["v"].dtype))
-        new_cache = {"k": k_all, "v": v_all}
-        out = _decode_sdpa(q, k_all.astype(cd), v_all.astype(cd), decode_mask)
+        # decode: write the step's row, then attend over the layer's cache
+        kv = kv_cache({"k": k[:, 0], "v": v[:, 0]})
+        out = _decode_sdpa(q, kv["k"].astype(cd), kv["v"].astype(cd),
+                           decode_mask)
     else:
         out = sdpa(q, k, v, causal=causal and xattn_kv is None,
                    window=window)
     out = lc(out, "batch", "seq", "heads", None)
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(cd))
-    return lc(y, "batch", "seq", None), new_cache
+    return lc(y, "batch", "seq", None)
 
 
 # ------------------------------------------------------------------- MLA
@@ -207,14 +206,14 @@ def init_mla(cfg: ModelConfig, rng) -> dict:
 
 
 def mla_apply(p: dict, cfg: ModelConfig, x: jnp.ndarray,
-              positions: jnp.ndarray, *, kv_cache=None,
-              cache_positions=None, decode_mask=None):
+              positions: jnp.ndarray, *, kv_cache=None, decode_mask=None):
     """Multi-head latent attention (DeepSeek-V2).
 
     Prefill/train: decompress K/V per head and run the shared chunked GQA
     core (rope and nope score terms fused via head-dim concat).
     Decode: *absorbed* path — score and combine directly in the compressed
-    c_kv space; the cache stores (c_kv, k_rope) only.
+    c_kv space; the cache stores (c_kv, k_rope) only.  kv_cache is the
+    layer's cache writer, as in :func:`attention_apply`.
     """
     cd = cfg.cdtype
     hd, h, rh, r = cfg.hd, cfg.n_heads, cfg.rope_head_dim, cfg.kv_lora_rank
@@ -236,16 +235,10 @@ def mla_apply(p: dict, cfg: ModelConfig, x: jnp.ndarray,
         k_cat = jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_rope, k_nope.shape[:3] + (rh,))], -1)
         out = sdpa(q_cat, k_cat, vv, causal=True, scale=scale)
-        new_cache = None
     else:
-        idx = cache_positions
-        bidx = jnp.arange(x.shape[0])
-        kr = apply_rope(k_rope_new[:, :, None, :], cos, sin)[:, :, 0]
-        ckv_all = kv_cache["c_kv"].at[bidx, idx].set(
-            c_kv[:, 0].astype(kv_cache["c_kv"].dtype))
-        kr_all = kv_cache["k_rope"].at[bidx, idx].set(
-            kr[:, 0].astype(kv_cache["k_rope"].dtype))
-        new_cache = {"c_kv": ckv_all, "k_rope": kr_all}
+        kr = apply_rope(k_rope_new[:, :, None, :], cos, sin)[:, 0, 0]
+        kv = kv_cache({"c_kv": c_kv[:, 0], "k_rope": kr})
+        ckv_all, kr_all = kv["c_kv"], kv["k_rope"]
         # absorbed attention in compressed space
         q_abs = jnp.einsum("bshk,rhk->bshr", q_nope, p["wuk"].astype(cd))
         with jax.named_scope("fused_attn"):
@@ -257,7 +250,7 @@ def mla_apply(p: dict, cfg: ModelConfig, x: jnp.ndarray,
             out_c = jnp.einsum("bhst,btr->bshr", w, ckv_all.astype(cd))
         out = jnp.einsum("bshr,rhk->bshk", out_c, p["wuv"].astype(cd))
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(cd))
-    return lc(y, "batch", "seq", None), new_cache
+    return lc(y, "batch", "seq", None)
 
 
 # ------------------------------------------------------------------- MLPs

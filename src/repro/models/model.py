@@ -2,7 +2,8 @@
 encoder-decoder (whisper) and VLM (frontend-stub) backbones.
 
 Layer stacks are scanned (``jax.lax.scan`` over stacked params) so HLO size
-and compile time are layer-count independent; each scanned block is
+and compile time are layer-count independent (the one-token decode step
+unrolls its scan, see ``Model._run_stack``); each scanned block is
 optionally rematerialized (``cfg.remat``) for training memory.
 
 Three entry points per model (built by :func:`build_model`):
@@ -37,8 +38,21 @@ def init_embeddings(cfg: ModelConfig, rng) -> dict:
     return p
 
 
-def embed(p: dict, cfg: ModelConfig, tokens: jnp.ndarray) -> jnp.ndarray:
-    x = jnp.take(p["tok"].astype(cfg.cdtype), tokens, axis=0)
+def embed(p: dict, cfg: ModelConfig, tokens: jnp.ndarray, *,
+          one_hot: bool = False) -> jnp.ndarray:
+    """Token rows of the embedding table.  ``one_hot`` looks them up as a
+    one-hot matmul over the whole table (exact: one nonzero term a row).
+    Decode uses it when the unembedding reads the same table each step
+    anyway: both then read it in its own layout, where a gather on TPU
+    first copies the whole table into row-major (94 MB a step for
+    SmolLM-360M)."""
+    tok = p["tok"].astype(cfg.cdtype)
+    if one_hot:
+        x = jnp.einsum("bsv,vd->bsd",
+                       jax.nn.one_hot(tokens, tok.shape[0], dtype=tok.dtype),
+                       tok)
+    else:
+        x = jnp.take(tok, tokens, axis=0)
     return lc(x, "batch", "seq", None)
 
 
@@ -76,10 +90,11 @@ def init_block(cfg: ModelConfig, rng) -> dict:
 
 def block_apply(p: dict, cfg: ModelConfig, x: jnp.ndarray,
                 positions: jnp.ndarray, decode_mask=None,
-                cache: Optional[dict] = None, cache_pos=None,
-                layer_is_slstm=None):
-    """Returns (x, new_cache).  decode_mask (B,T) marks valid cache slots
-    (decode only); train/prefill masks are banded on the fly."""
+                cache: Optional["LayerCache"] = None, layer_is_slstm=None):
+    """Returns x.  In decode, ``cache`` is the layer's view of the stacked
+    cache: the block reads its states from it and writes its updates into
+    it.  decode_mask (B,T) marks valid cache slots (decode only);
+    train/prefill masks are banded on the fly."""
     # TP-region input: gathered to FULL sequence exactly once here (Megatron
     # SP boundary); qkv/MLP dots consume it locally, outputs reduce-scatter
     # back into the seq-sharded residual (§Perf: constraining h to stay
@@ -87,36 +102,30 @@ def block_apply(p: dict, cfg: ModelConfig, x: jnp.ndarray,
     h = lc(L.rmsnorm(p["ln1"], x, cfg.norm_eps), "batch", "seq", "dmodel")
     h = jax.ad_checkpoint.checkpoint_name(h, "blk_attn_in")
     window = cfg.sliding_window if cfg.attention == "sliding" else 0
-    new_cache: dict = {}
+    kv_cache = None if cache is None else cache.writer("kv")
+    state = (lambda key: None) if cache is None else cache.read
     if cfg.family == "ssm":
-        m_out, m_state = S.mlstm_apply(p["mlstm"], cfg, h,
-                                       None if cache is None else cache["mlstm"])
-        s_out, s_state = S.slstm_apply(p["slstm"], cfg, h,
-                                       None if cache is None else cache["slstm"])
+        m_out, m_state = S.mlstm_apply(p["mlstm"], cfg, h, state("mlstm"))
+        s_out, s_state = S.slstm_apply(p["slstm"], cfg, h, state("slstm"))
         sel = layer_is_slstm.astype(h.dtype)
         attn_out = sel * s_out + (1 - sel) * m_out
-        new_cache = {"mlstm": m_state, "slstm": s_state}
+        if cache is not None:
+            cache.write("mlstm", m_state)
+            cache.write("slstm", s_state)
     elif cfg.family == "hybrid":
-        a_out, kv = L.attention_apply(
-            p["attn"], cfg, h, positions, window=window,
-            kv_cache=None if cache is None else cache["kv"],
-            cache_positions=cache_pos, decode_mask=decode_mask)
-        mb_out, mb_state = S.mamba_apply(
-            p["mamba"], cfg, h, None if cache is None else cache["mamba"])
+        a_out = L.attention_apply(p["attn"], cfg, h, positions, window=window,
+                                  kv_cache=kv_cache, decode_mask=decode_mask)
+        mb_out, mb_state = S.mamba_apply(p["mamba"], cfg, h, state("mamba"))
         attn_out = 0.5 * (a_out + mb_out)          # parallel heads (hymba)
-        new_cache = {"kv": kv, "mamba": mb_state}
+        if cache is not None:
+            cache.write("mamba", mb_state)
     elif cfg.attention == "mla":
-        attn_out, kv = L.mla_apply(p["attn"], cfg, h, positions,
-                                   kv_cache=None if cache is None else cache["kv"],
-                                   cache_positions=cache_pos,
-                                   decode_mask=decode_mask)
-        new_cache = {"kv": kv}
+        attn_out = L.mla_apply(p["attn"], cfg, h, positions,
+                               kv_cache=kv_cache, decode_mask=decode_mask)
     else:
-        attn_out, kv = L.attention_apply(
-            p["attn"], cfg, h, positions, window=window,
-            kv_cache=None if cache is None else cache["kv"],
-            cache_positions=cache_pos, decode_mask=decode_mask)
-        new_cache = {"kv": kv}
+        attn_out = L.attention_apply(p["attn"], cfg, h, positions,
+                                     window=window, kv_cache=kv_cache,
+                                     decode_mask=decode_mask)
     # residual stream is sequence-sharded between TP regions (Megatron SP);
     # "seq_sp" maps to the model axis for train/prefill of wide models
     x = lc(x + attn_out, "batch", "seq_sp", "dmodel")
@@ -126,7 +135,7 @@ def block_apply(p: dict, cfg: ModelConfig, x: jnp.ndarray,
         x = x + L.moe_apply(p["moe"], cfg, h2)
     elif cfg.d_ff > 0:
         x = x + L.mlp_apply(p["mlp"], cfg, h2)
-    return lc(x, "batch", "seq_sp", "dmodel"), new_cache
+    return lc(x, "batch", "seq_sp", "dmodel")
 
 
 # ----------------------------------------------------------- encoder blocks
@@ -141,7 +150,7 @@ def init_enc_block(cfg: ModelConfig, rng) -> dict:
 def enc_block_apply(p: dict, cfg: ModelConfig, x: jnp.ndarray,
                     positions: jnp.ndarray):
     h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
-    a, _ = L.attention_apply(p["attn"], cfg, h, positions, causal=False)
+    a = L.attention_apply(p["attn"], cfg, h, positions, causal=False)
     x = x + a
     h2 = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
     return x + L.mlp_apply(p["mlp"], cfg, h2)
@@ -158,20 +167,72 @@ def init_xattn_block(cfg: ModelConfig, rng) -> dict:
 
 
 def xattn_block_apply(p: dict, cfg: ModelConfig, x, positions,
-                      decode_mask, enc_out, cache=None, cache_pos=None):
+                      decode_mask, enc_out, cache=None):
     h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
-    a, kv = L.attention_apply(p["attn"], cfg, h, positions,
-                              kv_cache=None if cache is None else cache["kv"],
-                              cache_positions=cache_pos,
-                              decode_mask=decode_mask)
+    a = L.attention_apply(p["attn"], cfg, h, positions,
+                          kv_cache=None if cache is None else cache.writer("kv"),
+                          decode_mask=decode_mask)
     x = lc(x + a, "batch", "seq_sp", "dmodel")
     hx = L.rmsnorm(p["lnx"], x, cfg.norm_eps)
-    xa, _ = L.attention_apply(p["xattn"], cfg, hx, positions, causal=False,
-                              use_rope=False, xattn_kv=enc_out)
+    xa = L.attention_apply(p["xattn"], cfg, hx, positions, causal=False,
+                           use_rope=False, xattn_kv=enc_out)
     x = lc(x + xa, "batch", "seq_sp", "dmodel")
     h2 = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
     x = x + L.mlp_apply(p["mlp"], cfg, h2)
-    return lc(x, "batch", "seq_sp", "dmodel"), {"kv": kv}
+    return lc(x, "batch", "seq_sp", "dmodel")
+
+
+# ------------------------------------------------------------ decode cache
+def write_layer(buf: jnp.ndarray, layer, upd: jnp.ndarray,
+                write_at) -> jnp.ndarray:
+    """Write one layer's decode update into a stacked cache buffer
+    ``(L, B, ...)``, in place when the cache is donated.
+
+    The update follows the buffer's layout: a recurrent state comes whole,
+    ``(B, ...)``, and goes in at ``[layer]``; a positional cache ``(L, B, T,
+    ...)`` gets one row per slot, ``(B, ...)``, where ``write_at`` (B, T)
+    marks each slot's position (one per row, made once a step, outside the
+    layer loop).  The rows go in as a select over the layer's slice
+    written back with one dynamic-update-slice, which XLA fuses into one
+    in-place pass in whatever layout it gave the cache.  A row scatter is
+    not layout-free: on TPU it wants the row's axes minor, the padding-free
+    layout of a (T, 5, 64) cache puts T minor, and XLA then copies the whole
+    cache into the scatter's layout and back."""
+    upd = upd.astype(buf.dtype)
+    if upd.ndim == buf.ndim - 2:
+        old = jax.lax.dynamic_index_in_dim(buf, layer, 0, False,
+                                           allow_negative_indices=False)
+        at = write_at.reshape(write_at.shape + (1,) * (old.ndim - 2))
+        upd = jnp.where(at, upd[:, None], old)
+    return jax.lax.dynamic_update_index_in_dim(buf, upd, layer, 0,
+                                               allow_negative_indices=False)
+
+
+class LayerCache:
+    """Layer ``layer``'s view of the stacked decode cache that the layer
+    loop carries.  ``read(key)`` gives the layer's slice of ``stack[key]``;
+    ``write(key, new)`` puts the layer's update in (:func:`write_layer`) and
+    returns the slice as it is after the write, so attention reads the
+    current token from the same buffer the step updates in place."""
+
+    def __init__(self, stack: dict, layer, write_at):
+        self.stack, self.layer, self.write_at = stack, layer, write_at
+
+    def read(self, key: str):
+        return jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(
+                a, self.layer, 0, False, allow_negative_indices=False),
+            self.stack[key])
+
+    def write(self, key: str, new):
+        self.stack = {**self.stack, key: jax.tree.map(
+            lambda buf, upd: write_layer(buf, self.layer, upd,
+                                         self.write_at),
+            self.stack[key], new)}
+        return self.read(key)
+
+    def writer(self, key: str):
+        return lambda new: self.write(key, new)
 
 
 # ------------------------------------------------------------------- Model
@@ -213,22 +274,32 @@ class Model:
         return m
 
     def _run_stack(self, params, x, positions, decode_mask=None, cache=None,
-                   cache_pos=None, enc_out=None):
+                   write_at=None, enc_out=None):
+        """Apply the layer stack.  In decode the stacked cache rides in the
+        carry: layer ``l`` writes its update at ``[l]`` and reads its slice
+        back (:class:`LayerCache`), so a donated cache is updated in place
+        and no step copies it.  The decode scan is unrolled: a one-token
+        step is small, and a rolled loop's own work (slicing every weight
+        stack and the layer index, the trip counter, ops XLA cannot fuse
+        across the loop boundary) adds a quarter to its device ops."""
         cfg = self.cfg
         slstm_sel = self._slstm_mask() if cfg.family == "ssm" else None
 
-        def body(carry_x, scanned):
-            layer_p, layer_cache, sel = scanned
+        def body(carry, scanned):
+            carry_x, stack = carry
+            layer_p, sel, layer = scanned
+            view = None if stack is None else LayerCache(stack, layer,
+                                                         write_at)
             if enc_out is not None:
-                out, new_c = xattn_block_apply(layer_p, cfg, carry_x, positions,
-                                               decode_mask, enc_out,
-                                               layer_cache, cache_pos)
+                out = xattn_block_apply(layer_p, cfg, carry_x, positions,
+                                        decode_mask, enc_out, view)
             else:
-                out, new_c = block_apply(layer_p, cfg, carry_x, positions,
-                                         decode_mask, layer_cache, cache_pos,
-                                         layer_is_slstm=sel)
-            return out, new_c
+                out = block_apply(layer_p, cfg, carry_x, positions,
+                                  decode_mask, view, layer_is_slstm=sel)
+            return (out, None if view is None else view.stack), None
 
+        sel = (slstm_sel if slstm_sel is not None
+               else jnp.zeros((cfg.n_layers, 1, 1, 1)))
         if cfg.scan_layers:
             fn = body
             if cfg.remat:
@@ -236,24 +307,14 @@ class Model:
                     "blk_attn_in", "blk_mlp_in")
                     if cfg.remat_policy == "save_boundaries" else None)
                 fn = jax.checkpoint(body, prevent_cse=False, policy=policy)
-            sel = (slstm_sel if slstm_sel is not None
-                   else jnp.zeros((cfg.n_layers, 1, 1, 1)))
-            x, new_cache = jax.lax.scan(
-                lambda c, s: fn(c, s), x,
-                (params["blocks"], cache, sel))
-            return x, new_cache
-        new_caches = []
+            (x, cache), _ = jax.lax.scan(
+                fn, (x, cache),
+                (params["blocks"], sel, jnp.arange(cfg.n_layers)),
+                unroll=cache is not None)
+            return x, cache
         for i in range(cfg.n_layers):
-            layer_cache = None if cache is None else jax.tree.map(
-                lambda a: a[i], cache)
-            sel = (slstm_sel[i] if slstm_sel is not None else jnp.zeros((1, 1, 1)))
-            x, nc = body(x, (params["blocks"][i], layer_cache, sel))
-            new_caches.append(nc)
-        if new_caches and new_caches[0]:
-            new_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *new_caches)
-        else:
-            new_cache = None
-        return x, new_cache
+            (x, cache), _ = body((x, cache), (params["blocks"][i], sel[i], i))
+        return x, cache
 
     def _encode(self, params, audio_embeds):
         cfg = self.cfg
@@ -332,11 +393,14 @@ class Model:
     # -- decode --------------------------------------------------------
     def decode_step(self, params, cache, batch: dict):
         """One-token decode.  batch: tokens (B,1), pos (B,) current position,
-        plus enc/vlm extras.  Cache is functional (returned updated)."""
+        plus enc/vlm extras.  Returns the logits and the cache with each
+        layer's new row (or recurrent state) written in.  The serving jit
+        donates the cache (``ServingEngine``), so a call consumes its input
+        cache and updates it in place: use only the returned one."""
         cfg = self.cfg
         tokens, pos = batch["tokens"], batch["pos"]
         b = tokens.shape[0]
-        x = embed(params["emb"], cfg, tokens)
+        x = embed(params["emb"], cfg, tokens, one_hot=cfg.tie_embeddings)
         positions = pos[:, None]
         # enc-dec decode: encoder output was computed once at prefill and is
         # carried alongside the cache (real engines cache cross-attn KV)
@@ -344,23 +408,23 @@ class Model:
 
         if cfg.family == "ssm":
             decode_mask = None
-            cache_pos = None
+            write_at = None                 # recurrent states only
         elif cfg.family == "hybrid":
             # ring-buffer window cache: slot i holds absolute position
             # p ≡ i (mod W); mask stale/unwritten/out-of-window slots
             w = cache["kv"]["k"].shape[2]
-            cache_pos = jnp.mod(pos, w)
             slot_age = jnp.mod(pos[:, None] - jnp.arange(w)[None], w)
+            write_at = slot_age == 0                      # (B, W)
             valid = (pos[:, None] - slot_age) >= 0
             within = slot_age < (cfg.sliding_window or 10**9)
             decode_mask = valid & within                  # (B, W)
         else:
             max_seq = (cache["kv"]["k"].shape[2] if cfg.attention != "mla"
                        else cache["kv"]["c_kv"].shape[2])
-            cache_pos = pos
+            write_at = jnp.arange(max_seq)[None] == pos[:, None]     # (B,S)
             decode_mask = jnp.arange(max_seq)[None] <= pos[:, None]  # (B,S)
         x, new_cache = self._run_stack(params, x, positions, decode_mask,
-                                       cache=cache, cache_pos=cache_pos,
+                                       cache=cache, write_at=write_at,
                                        enc_out=enc_out)
         logits = unembed(params["emb"], cfg, x)
         return logits[:, 0], new_cache

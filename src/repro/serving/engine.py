@@ -92,7 +92,10 @@ class ServingEngine:
         self.model = build_model(model_cfg)
         rng = rng if rng is not None else jax.random.PRNGKey(0)
         self.params = params if params is not None else self.model.init(rng)
-        self.decode = jax.jit(make_decode_step(self.model))
+        # the KV cache is donated: each step writes its rows in place and
+        # the loop rebinds ``cache`` from the step's output
+        self.decode = jax.jit(make_decode_step(self.model),
+                              donate_argnums=(1,))
         # one tracker instance shared with the cache: engine request-path
         # spans and cache.* latencies land in the same registry/trace
         self._trk = make_tracker(ecfg.tracker)

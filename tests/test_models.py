@@ -90,6 +90,55 @@ def test_decode_matches_forward(arch):
         f"decode/forward divergence {max(tail)} (per-pos {tail})"
 
 
+# slots of the engine's batch: each runs its own prompts back to back, so
+# after the first restart the slots sit at different positions
+SEGMENTS = [(12,), (5, 7), (3, 9)]
+
+
+@pytest.mark.parametrize("arch,scan", [
+    ("smollm_360m", True), ("smollm_360m", False),
+    ("deepseek_v2_lite_16b", False), ("hymba_15b", True),
+    ("xlstm_125m", True), ("xlstm_125m", False)])
+def test_engine_decode_matches_forward(arch, scan):
+    """The serving engine's decode jit, which donates the cache, stepped
+    with its slots at different positions, reproduces each slot's
+    teacher-forced forward logits; each call consumes the cache it is
+    handed.  A slot whose cache holds a recurrent state carries it across
+    prompts, so those slots run one prompt each (still a whole-state write
+    per layer)."""
+    from repro.serving import EngineConfig, ServingEngine
+    cfg = dataclasses.replace(smoke_variant(get_config(arch)),
+                              scan_layers=scan)
+    model = build_model(cfg)
+    params = model.init(RNG)
+    eng = ServingEngine(cfg, EngineConfig(max_batch=len(SEGMENTS),
+                                          max_seq=16), params=params)
+    recurrent = cfg.family in ("ssm", "hybrid")
+    segs = [(sum(s),) if recurrent else s for s in SEGMENTS]
+    rng = np.random.default_rng(1)
+    toks = [[rng.integers(2, cfg.vocab_size, n) for n in s] for s in segs]
+    full = [[model.forward(params, {"tokens": jnp.asarray(t[None])})[0]
+             for t in row] for row in toks]
+    # (segment, position) of every slot at every step
+    sched = [[(i, p) for i, n in enumerate(s) for p in range(n)]
+             for s in segs]
+    cache = model.init_cache(len(segs), 16)
+    errs = []
+    for step in range(len(sched[0])):
+        at = [row[step] for row in sched]
+        batch = {"tokens": jnp.asarray([[toks[b][i][p]]
+                                        for b, (i, p) in enumerate(at)],
+                                       jnp.int32),
+                 "pos": jnp.asarray([p for _, p in at], jnp.int32)}
+        given = cache
+        _, logits, cache = eng.decode(params, given, batch)
+        assert all(a.is_deleted() for a in jax.tree.leaves(given))
+        errs += [float(jnp.abs(logits[b] - full[b][i][p]).max())
+                 for b, (i, p) in enumerate(at)]
+    assert max(errs) < (2e-1 if recurrent else 5e-2), max(errs)
+    eng.close()
+
+
 def test_moe_routing_conserves_tokens():
     """Capacity-factor dispatch: with ample capacity every token's top-k
     mass is preserved (combine weights sum to 1 per token)."""

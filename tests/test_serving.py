@@ -1,5 +1,10 @@
 """Serving integration: engine cache behavior + RAC-scored KV-block
 manager (radix validity, prefix reuse, eviction under pressure)."""
+import dataclasses
+import re
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -67,6 +72,38 @@ def test_engine_async_admit_matches_sync():
     assert out_sync == out_async
     for k in ("hits", "misses", "evictions", "generated_tokens", "batches"):
         assert s_sync[k] == s_async[k], k
+
+
+def test_decode_step_updates_the_cache_in_place():
+    """The engine's decode jit donates the stacked KV cache and writes each
+    layer's row into it: the cache leaves are aliased input to output, a
+    call deletes the cache it was handed, and the optimized program makes
+    no cache-sized buffer of its own (no copy of the stacked cache or of a
+    layer's slice, no fresh stacked buffer broadcast for the layer scan's
+    output).  float32: XLA:CPU rewrites bfloat16 with converts of the whole
+    cache that the TPU does not have."""
+    mcfg = dataclasses.replace(smoke_variant(get_config("smollm_360m")),
+                               n_layers=3, scan_layers=True)
+    eng = ServingEngine(mcfg, EngineConfig(max_batch=8, max_seq=256))
+    cache = eng.model.init_cache(8, 256)
+    batch = {"tokens": jnp.zeros((8, 1), jnp.int32),
+             "pos": jnp.arange(8, dtype=jnp.int32)}
+    text = eng.decode.lower(eng.params, cache, batch).compile().as_text()
+    leaf = jax.tree.leaves(cache)[0]
+
+    def made(shape, ops):
+        return re.findall(rf"= {re.escape('f32[%s]' % ','.join(map(str, shape)))}"
+                          rf"\{{[^}}]*\}} ({ops})\(", text)
+    assert not made(leaf.shape, "copy|broadcast")
+    assert not made(leaf.shape[1:], "copy")
+    n_params = len(jax.tree.leaves(eng.params))
+    aliased = re.findall(r"\{\d+\}: \((\d+), \{\}", text)
+    assert {int(a) for a in aliased} == set(
+        range(n_params, n_params + len(jax.tree.leaves(cache))))
+    _, _, out = eng.decode(eng.params, cache, batch)
+    assert all(a.is_deleted() for a in jax.tree.leaves(cache))
+    assert not any(a.is_deleted() for a in jax.tree.leaves(out))
+    eng.close()
 
 
 # ------------------------------------------------------------ KV blocks

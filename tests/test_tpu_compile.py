@@ -5,7 +5,8 @@ compiles each program for a ``v5e:2x2`` topology and refuses what the chip
 would refuse (tiling that Mosaic and XLA disagree on, unsupported in-kernel
 ops, VMEM overruns) — faults that interpret mode cannot show.  Widths are
 a deployment's: a 65,536-row slab of 384-wide embeddings (an encoder such
-as all-MiniLM-L6-v2).
+as all-MiniLM-L6-v2), and the served model's decode step at SmolLM-360M's
+widths, where the chip's layouts decide whether the KV cache is copied.
 
 The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU library, and every
@@ -22,6 +23,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels import fused, ops
+from repro.models import build_model
 from repro.kernels.decision import victim_value_pallas
 from repro.kernels.rac_value import rac_value_pallas
 from repro.kernels.similarity_topk import (sim_top1_pallas, sim_topk_pallas,
@@ -137,3 +139,43 @@ def test_fused_pruned_body_compiles(one_chip):
              ((T, D + 1), F32), ((T + 2,), I32), ((N,), I32),
              ((), I32), ((), I32), ((), I32), ((), F32),
              kernels=["sim_topk", "sim_top1"])
+
+
+@pytest.mark.parametrize("scan", [True, False])
+def test_decode_step_keeps_the_cache_in_place(one_chip, scan):
+    """The serving engine's decode step at SmolLM-360M's widths (4 of its
+    32 layers), 64 slots of 256 positions in bfloat16: the donated cache is
+    aliased input to output, and the TPU program neither copies the
+    stacked cache or a layer's slice (as it does when the chip's layout for
+    the cache, positions minor, differs from the one the write wants) nor
+    builds a fresh stacked buffer."""
+    import dataclasses
+    from repro.configs import get_config
+    from repro.serving import EngineConfig, ServingEngine
+    b, s = 64, 256
+    cfg = dataclasses.replace(get_config("smollm_360m"), n_layers=4,
+                              scan_layers=scan, param_dtype="bfloat16",
+                              compute_dtype="bfloat16")
+    eng = ServingEngine(cfg, EngineConfig(max_batch=b, max_seq=s),
+                        params=jax.eval_shape(
+                            build_model(cfg).init, jax.random.PRNGKey(0)))
+    on_chip = functools.partial(jax.tree.map, lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip))
+    cache = on_chip(eng.model.cache_shape_structs(b, s))
+    batch = on_chip({"tokens": jax.ShapeDtypeStruct((b, 1), I32),
+                     "pos": jax.ShapeDtypeStruct((b,), I32)})
+    text = eng.decode.lower(on_chip(eng.params), cache,
+                            batch).compile().as_text()
+    eng.close()
+    leaf = jax.tree.leaves(cache)[0]
+
+    def made(shape, ops):
+        return re.findall(rf"= {re.escape('bf16[%s]' % ','.join(map(str, shape)))}"
+                          rf"\{{[^}}]*\}} ({ops})\(", text)
+    assert not made(leaf.shape, "copy|broadcast")
+    assert not made(leaf.shape[1:], "copy")
+    assert not made((1,) + leaf.shape[1:], "copy")
+    n_params = len(jax.tree.leaves(eng.params))
+    aliased = re.findall(r"\{\d+\}: \((\d+), \{\}", text)
+    assert {int(a) for a in aliased} == set(
+        range(n_params, n_params + len(jax.tree.leaves(cache))))
