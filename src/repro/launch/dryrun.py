@@ -115,8 +115,9 @@ def lower_cell(arch: str, shape: str, multi_pod: bool,
     if cfg.is_moe:
         e_ff = cfg.expert_d_ff or cfg.d_ff
         n_in = 3 if cfg.mlp in ("swiglu", "geglu") else 2
-        moe_total = cfg.n_layers * cfg.n_experts * n_in * cfg.d_model * e_ff
-        moe_active = cfg.n_layers * cfg.top_k * n_in * cfg.d_model * e_ff
+        n_moe = cfg.n_layers - cfg.n_dense_layers     # leading dense: none
+        moe_total = n_moe * cfg.n_experts * n_in * cfg.d_model * e_ff
+        moe_active = n_moe * cfg.top_k * n_in * cfg.d_model * e_ff
         active = n_params - moe_total + moe_active
     tokens = sc.global_batch * (sc.seq_len if sc.kind != "decode" else 1)
     model_flops = (6 if sc.kind == "train" else 2) * active * tokens

@@ -8,11 +8,39 @@ shards (noted in DESIGN.md §8).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional, Sequence
 
 import jax.numpy as jnp
 
 VOCAB_PAD = 2048      # 16-way TP x 128-lane alignment
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rope scaling (HF ``rope_scaling`` of type ``yarn``, as
+    DeepSeek-V2's ``modeling_deepseek.py`` applies it)."""
+    factor: float
+    original_max_pos: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def get_mscale(self, m: float) -> float:
+        return 1.0 if self.factor <= 1 else 0.1 * m * math.log(self.factor) + 1.0
+
+    @property
+    def softmax_mscale(self) -> float:
+        """Factor on the attention's softmax scale (mscale squared)."""
+        if not self.mscale_all_dim:
+            return 1.0
+        return self.get_mscale(self.mscale_all_dim) ** 2
+
+    @property
+    def cos_sin_scale(self) -> float:
+        return (self.get_mscale(self.mscale)
+                / self.get_mscale(self.mscale_all_dim))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -32,16 +60,19 @@ class ModelConfig:
     qkv_bias: bool = False
     sliding_window: int = 0      # for attention == "sliding"
     rope_theta: float = 10_000.0
+    yarn: Optional[Yarn] = None
 
     # mlp
     mlp: str = "swiglu"          # swiglu | geglu | relu2 | gelu
 
-    # MoE
+    # MoE: the first n_dense_layers blocks are dense MLPs of width d_ff,
+    # the rest route each token to top_k of n_experts (width expert_d_ff)
     n_experts: int = 0
     n_shared_experts: int = 0
     top_k: int = 0
     expert_d_ff: int = 0
-    capacity_factor: float = 1.25
+    n_dense_layers: int = 0
+    norm_topk_prob: bool = True  # renormalize the top-k softmax weights
 
     # MLA (deepseek)
     kv_lora_rank: int = 0
@@ -101,37 +132,40 @@ class ModelConfig:
             self.family == "hybrid" and self.attention == "sliding")
 
     def n_params(self) -> int:
-        """Analytic parameter count (embedding + blocks + head), unpadded."""
+        """Analytic parameter count (embedding, blocks, norms, head),
+        unpadded."""
         d, hd, v = self.d_model, self.hd, self.vocab_size
-        emb = v * d * (1 if self.tie_embeddings else 2)
+        emb = v * d * (1 if self.tie_embeddings else 2) + d     # + final norm
+        n_in = 2 if self.mlp in ("swiglu", "geglu") else 1
         per_layer = 0
         if self.family == "ssm":
             di = self.xlstm_expand * d
             per_layer = 2 * d * di + di * (3 * di) + 2 * d   # rough xLSTM block
         else:
             if self.attention == "mla":
-                qk = d * (self.n_heads * (hd + self.rope_head_dim))
-                kv = d * self.kv_lora_rank + self.kv_lora_rank * self.n_heads * (hd + hd)
-                o = self.n_heads * hd * d
-                per_layer += qk + kv + o + d * self.rope_head_dim
+                r, rh = self.kv_lora_rank, self.rope_head_dim
+                per_layer += d * self.n_heads * (hd + rh)     # q
+                per_layer += d * (r + rh) + r                 # kv_a, its norm
+                per_layer += r * self.n_heads * (hd + hd)     # kv_b
+                per_layer += self.n_heads * hd * d            # o
             elif self.attention != "none":
                 per_layer += d * self.n_heads * hd            # q
                 per_layer += 2 * d * self.n_kv_heads * hd     # k, v
                 per_layer += self.n_heads * hd * d            # o
-            if self.is_moe:
-                e_ff = self.expert_d_ff or self.d_ff
-                n_in = 2 if self.mlp in ("swiglu", "geglu") else 1
-                per_layer += self.n_experts * (n_in + 1) * d * e_ff
-                per_layer += self.n_shared_experts * (n_in + 1) * d * e_ff
-                per_layer += d * self.n_experts                # router
-            elif self.d_ff > 0:
-                n_in = 2 if self.mlp in ("swiglu", "geglu") else 1
-                per_layer += (n_in + 1) * d * self.d_ff
             if self.family == "hybrid" and self.ssm_state > 0:
                 di = self.ssm_expand * d
                 per_layer += 2 * d * di + di * d + di * (2 * self.ssm_state + 1)
             per_layer += 2 * d                                 # norms
-        total = emb + self.n_layers * per_layer
+        dense = ((n_in + 1) * d * self.d_ff
+                 if self.d_ff > 0 and self.family != "ssm" else 0)
+        n_moe = self.n_layers - self.n_dense_layers if self.is_moe else 0
+        moe = 0
+        if self.is_moe:
+            e_ff = self.expert_d_ff or self.d_ff
+            moe = ((self.n_experts + self.n_shared_experts) * (n_in + 1) * d
+                   * e_ff + d * self.n_experts)                # + router
+        total = (emb + self.n_layers * per_layer + n_moe * moe
+                 + (self.n_layers - n_moe) * dense)
         if self.n_enc_layers:
             enc_layer = 4 * d * self.n_heads * hd + 3 * d * self.d_ff + 2 * d
             total += self.n_enc_layers * enc_layer
@@ -172,7 +206,7 @@ def smoke_variant(cfg: ModelConfig) -> ModelConfig:
         n_shared_experts=min(cfg.n_shared_experts, 1),
         top_k=min(cfg.top_k, 2),
         expert_d_ff=64 if cfg.expert_d_ff else 0,
-        capacity_factor=4.0,     # tiny-T smoke batches: avoid routing drops
+        n_dense_layers=min(cfg.n_dense_layers, 1),
         kv_lora_rank=64 if cfg.kv_lora_rank else 0,
         rope_head_dim=16 if cfg.kv_lora_rank else 64,
         ssm_state=min(cfg.ssm_state, 8),

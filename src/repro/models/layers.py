@@ -1,6 +1,6 @@
-"""Composable transformer layers: norms, RoPE, attention (GQA / MLA /
-sliding-window / cross), MLPs (SwiGLU / GeGLU / squared-ReLU / GELU) and
-capacity-factor MoE with token dispatch.
+"""Composable transformer layers: norms, RoPE (plain or YaRN), attention
+(GQA / MLA / sliding-window / cross), MLPs (SwiGLU / GeGLU / squared-ReLU /
+GELU) and a dropless top-k MoE.
 
 Functional style: ``init_*`` builds a param dict; ``*_apply`` consumes it.
 Activations are annotated with logical axes via ``repro.distributed.api.lc``
@@ -8,14 +8,14 @@ Activations are annotated with logical axes via ``repro.distributed.api.lc``
 """
 from __future__ import annotations
 
-import dataclasses
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.distributed.api import lc
-from .config import ModelConfig
+from .config import ModelConfig, Yarn
 
 
 def _norm_init(d: int, dtype) -> dict:
@@ -30,11 +30,33 @@ def rmsnorm(p: dict, x: jnp.ndarray, eps: float) -> jnp.ndarray:
 
 
 # ------------------------------------------------------------------- RoPE
-def rope_cos_sin(positions: jnp.ndarray, dim: int, theta: float):
+def yarn_inv_freq(dim: int, theta: float, yarn: Yarn) -> jnp.ndarray:
+    """YaRN's frequencies: the plain ones below the correction range,
+    ``1/factor`` of them above it, a linear ramp between (HF's
+    ``DeepseekV2YarnRotaryEmbedding``)."""
+    def corr(rot):
+        return (dim * math.log(yarn.original_max_pos / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(corr(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr(yarn.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    extra = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0, 1)
+    return extra / yarn.factor * ramp + extra * (1 - ramp)
+
+
+def rope_cos_sin(positions: jnp.ndarray, dim: int, theta: float,
+                 yarn: Optional[Yarn] = None):
     """positions: (...,) int32 -> cos/sin of shape (..., dim//2)."""
-    inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
-    ang = positions.astype(jnp.float32)[..., None] * inv
-    return jnp.cos(ang), jnp.sin(ang)
+    if yarn is None:
+        inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+        ang = positions.astype(jnp.float32)[..., None] * inv
+        return jnp.cos(ang), jnp.sin(ang)
+    ang = positions.astype(jnp.float32)[..., None] * yarn_inv_freq(
+        dim, theta, yarn)
+    return jnp.cos(ang) * yarn.cos_sin_scale, jnp.sin(ang) * yarn.cos_sin_scale
 
 
 def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray):
@@ -198,11 +220,18 @@ def init_mla(cfg: ModelConfig, rng) -> dict:
     return {
         "wq": jax.random.normal(k[0], (d, h, hd + rh), cfg.pdtype) * s,
         "wdkv": jax.random.normal(k[1], (d, r), cfg.pdtype) * s,
+        "kv_norm": _norm_init(r, cfg.pdtype),
         "wuk": jax.random.normal(k[2], (r, h, hd), cfg.pdtype) * s,
         "wuv": jax.random.normal(k[3], (r, h, hd), cfg.pdtype) * s,
         "wkr": jax.random.normal(k[4], (d, rh), cfg.pdtype) * s,
         "wo": jax.random.normal(k[5], (h, hd, d), cfg.pdtype) * s,
     }
+
+
+def _pairs_to_halves(x: jnp.ndarray) -> jnp.ndarray:
+    """Rope dims paired (2i, 2i+1), as DeepSeek-V2's checkpoints lay them,
+    moved to the halves (i, i + dim/2) that :func:`apply_rope` rotates."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
 
 
 def mla_apply(p: dict, cfg: ModelConfig, x: jnp.ndarray,
@@ -212,20 +241,27 @@ def mla_apply(p: dict, cfg: ModelConfig, x: jnp.ndarray,
     Prefill/train: decompress K/V per head and run the shared chunked GQA
     core (rope and nope score terms fused via head-dim concat).
     Decode: *absorbed* path — score and combine directly in the compressed
-    c_kv space; the cache stores (c_kv, k_rope) only.  kv_cache is the
-    layer's cache writer, as in :func:`attention_apply`.
+    c_kv space; the cache stores (c_kv, k_rope) only, c_kv after its RMSNorm
+    (``kv_a_layernorm``).  kv_cache is the layer's cache writer, as in
+    :func:`attention_apply`.  With YaRN the rope frequencies are YaRN's and
+    the softmax scale carries mscale squared.
     """
     cd = cfg.cdtype
     hd, h, rh, r = cfg.hd, cfg.n_heads, cfg.rope_head_dim, cfg.kv_lora_rank
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(cd))
     q = lc(q, "batch", "seq", "heads", None)
-    q_nope, q_rope = q[..., :hd], q[..., hd:]
-    cos, sin = rope_cos_sin(positions, rh, cfg.rope_theta)
+    q_nope, q_rope = q[..., :hd], _pairs_to_halves(q[..., hd:])
+    cos, sin = rope_cos_sin(positions, rh, cfg.rope_theta, cfg.yarn)
     q_rope = apply_rope(q_rope, cos, sin)
 
-    c_kv = jnp.einsum("bsd,dr->bsr", x, p["wdkv"].astype(cd))      # (B,S,r)
-    k_rope_new = jnp.einsum("bsd,dk->bsk", x, p["wkr"].astype(cd))  # (B,S,rh)
-    scale = 1.0 / float(hd + rh) ** 0.5
+    c_kv = rmsnorm(p["kv_norm"],
+                   jnp.einsum("bsd,dr->bsr", x, p["wdkv"].astype(cd)),
+                   cfg.norm_eps)                                   # (B,S,r)
+    k_rope_new = _pairs_to_halves(
+        jnp.einsum("bsd,dk->bsk", x, p["wkr"].astype(cd)))         # (B,S,rh)
+    scale = float(hd + rh) ** -0.5
+    if cfg.yarn is not None:
+        scale *= cfg.yarn.softmax_mscale
     if kv_cache is None:
         k_rope = apply_rope(k_rope_new[:, :, None, :], cos, sin)
         k_nope = jnp.einsum("bsr,rhk->bshk", c_kv, p["wuk"].astype(cd))
@@ -304,55 +340,53 @@ def init_moe(cfg: ModelConfig, rng) -> dict:
     if _n_in(cfg.mlp) == 2:
         p["wg"] = jax.random.normal(k[2], (e, d, f), cfg.pdtype) * s
     if cfg.n_shared_experts:
-        sf = f * cfg.n_shared_experts
-        sub = dataclasses.replace(cfg, d_ff=sf)
-        p["shared"] = init_mlp(sub, k[4], d_ff=sf)
+        p["shared"] = init_mlp(cfg, k[4], d_ff=f * cfg.n_shared_experts)
     return p
 
 
-def moe_apply(p: dict, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
-    """Top-k capacity-factor MoE with scatter dispatch (Switch-style).
+def moe_route(p: dict, cfg: ModelConfig, xt: jnp.ndarray):
+    """Each row's top-k experts and their weights: softmax over the
+    router's logits in float32, greedy top-k, renormalized only with
+    ``norm_topk_prob`` (HF's ``routed_scaling_factor`` is 1 in every model
+    here).  xt (T, d) -> (T, K) weights (float32) and expert ids."""
+    logits = xt.astype(jnp.float32) @ p["router"].astype(jnp.float32)
+    topv, topi = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.top_k)
+    if cfg.norm_topk_prob:
+        topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
+    return topv, topi
 
-    Expert buffers are sharded over the ``expert`` logical axis (EP); the
-    scatter/gather between token- and expert-sharded layouts lowers to
-    all-to-all under SPMD.
+
+def moe_apply(p: dict, cfg: ModelConfig, x: jnp.ndarray):
+    """Dropless top-k MoE.  Returns ``(y, counts)``.
+
+    The token-expert pairs are sorted by expert and every expert's rows go
+    through one grouped matmul (``jax.lax.ragged_dot``; on TPU a grouped
+    matmul kernel that reads each expert's weights once), so no pair is
+    dropped however the router loads the experts.  The weighted sum over a
+    token's experts is taken in float32, beside the shared experts.
+    ``counts`` (int32[4]) is what the routing did: token-expert pairs,
+    experts with at least one row, the busiest expert's rows, and 1 (the
+    layer-step), for the decode cache's MoE counters.
     """
     cd = cfg.cdtype
     b, s_len, d = x.shape
     e, k = cfg.n_experts, cfg.top_k
     xt = x.reshape(-1, d)                                   # (T, d)
     t = xt.shape[0]
-    cap = max(1, -(-int(t * k * cfg.capacity_factor) // e))  # ceil division
-
-    logits = (xt.astype(jnp.float32) @ p["router"])          # (T, E) fp32
-    probs = jax.nn.softmax(logits, axis=-1)
-    topv, topi = jax.lax.top_k(probs, k)                     # (T, K)
-    topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
-
-    e_flat = topi.reshape(-1)                                # (T*K,)
-    onehot = jax.nn.one_hot(e_flat, e, dtype=jnp.int32)      # (T*K, E)
-    pos = jnp.cumsum(onehot, axis=0) * onehot                # running count
-    pos_in_e = pos.sum(-1) - 1                               # (T*K,)
-    keep = pos_in_e < cap
-    src = jnp.repeat(jnp.arange(t), k)
-    safe_pos = jnp.where(keep, pos_in_e, cap - 1)
-
-    buf = jnp.zeros((e, cap, d), cd)
-    buf = buf.at[jnp.where(keep, e_flat, e - 1), safe_pos].add(
-        jnp.where(keep[:, None], xt[src].astype(cd), 0))
-    buf = lc(buf, "expert", None, None)
-
-    h = jnp.einsum("ecd,edf->ecf", buf, p["wi"].astype(cd))
-    g = (jnp.einsum("ecd,edf->ecf", buf, p["wg"].astype(cd))
+    topv, topi = moe_route(p, cfg, xt)
+    e_flat = topi.reshape(-1)                               # (T*K,)
+    order = jnp.argsort(e_flat)                             # pairs by expert
+    sizes = jnp.bincount(e_flat, length=e).astype(jnp.int32)
+    xs = xt[order // k].astype(cd)                          # (T*K, d)
+    h = jax.lax.ragged_dot(xs, p["wi"].astype(cd), sizes)
+    g = (jax.lax.ragged_dot(xs, p["wg"].astype(cd), sizes)
          if "wg" in p else None)
-    h = _act(h, g, cfg.mlp)
-    out_buf = jnp.einsum("ecf,efd->ecd", h, p["wo"].astype(cd))
-    out_buf = lc(out_buf, "expert", None, None)
-
-    gathered = out_buf[e_flat, safe_pos]                     # (T*K, d)
-    gathered = jnp.where(keep[:, None], gathered, 0)
-    y = (gathered.reshape(t, k, d) *
-         topv.reshape(t, k, 1).astype(cd)).sum(axis=1)
+    out = jax.lax.ragged_dot(_act(h, g, cfg.mlp), p["wo"].astype(cd), sizes)
+    pairs = out[jnp.argsort(order)]                         # token order
+    y = jnp.einsum("tkd,tk->td", pairs.reshape(t, k, d).astype(jnp.float32),
+                   topv)
     if "shared" in p:
         y = y + mlp_apply(p["shared"], cfg, x).reshape(t, d)
-    return lc(y.reshape(b, s_len, d), "batch", "seq", None)
+    counts = jnp.stack([jnp.int32(t * k), jnp.sum(sizes > 0, dtype=jnp.int32),
+                        jnp.max(sizes), jnp.int32(1)])
+    return lc(y.astype(cd).reshape(b, s_len, d), "batch", "seq", None), counts

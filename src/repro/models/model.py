@@ -65,8 +65,9 @@ def unembed(p: dict, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
 
 
 # ------------------------------------------------------------------ blocks
-def init_block(cfg: ModelConfig, rng) -> dict:
-    """One decoder block's params (family-dependent)."""
+def init_block(cfg: ModelConfig, rng, moe: Optional[bool] = None) -> dict:
+    """One decoder block's params (family-dependent); ``moe=False`` makes
+    one of an MoE model's leading dense blocks."""
     ks = jax.random.split(rng, 6)
     p: dict[str, Any] = {"ln1": L._norm_init(cfg.d_model, cfg.pdtype),
                          "ln2": L._norm_init(cfg.d_model, cfg.pdtype)}
@@ -81,7 +82,7 @@ def init_block(cfg: ModelConfig, rng) -> dict:
         p["attn"] = L.init_attention(cfg, ks[0])
     if cfg.family == "hybrid" and cfg.ssm_state > 0:
         p["mamba"] = S.init_mamba(cfg, ks[1])
-    if cfg.is_moe:
+    if cfg.is_moe and moe is not False:
         p["moe"] = L.init_moe(cfg, ks[2])
     elif cfg.d_ff > 0:
         p["mlp"] = L.init_mlp(cfg, ks[2])
@@ -93,8 +94,10 @@ def block_apply(p: dict, cfg: ModelConfig, x: jnp.ndarray,
                 cache: Optional["LayerCache"] = None, layer_is_slstm=None):
     """Returns x.  In decode, ``cache`` is the layer's view of the stacked
     cache: the block reads its states from it and writes its updates into
-    it.  decode_mask (B,T) marks valid cache slots (decode only);
-    train/prefill masks are banded on the fly."""
+    it, and an MoE block adds its routing counts to its ``moe_stats``.
+    decode_mask (B,T) marks valid cache slots (decode only); train/prefill
+    masks are banded on the fly.  The block's params say whether its MLP is
+    routed (``moe``) or dense (``mlp``)."""
     # TP-region input: gathered to FULL sequence exactly once here (Megatron
     # SP boundary); qkv/MLP dots consume it locally, outputs reduce-scatter
     # back into the seq-sharded residual (§Perf: constraining h to stay
@@ -131,9 +134,12 @@ def block_apply(p: dict, cfg: ModelConfig, x: jnp.ndarray,
     x = lc(x + attn_out, "batch", "seq_sp", "dmodel")
     h2 = lc(L.rmsnorm(p["ln2"], x, cfg.norm_eps), "batch", "seq", "dmodel")
     h2 = jax.ad_checkpoint.checkpoint_name(h2, "blk_mlp_in")
-    if cfg.is_moe:
-        x = x + L.moe_apply(p["moe"], cfg, h2)
-    elif cfg.d_ff > 0:
+    if "moe" in p:
+        y, counts = L.moe_apply(p["moe"], cfg, h2)
+        x = x + y
+        if cache is not None:
+            cache.add("moe_stats", counts)
+    elif "mlp" in p:
         x = x + L.mlp_apply(p["mlp"], cfg, h2)
     return lc(x, "batch", "seq_sp", "dmodel")
 
@@ -213,7 +219,8 @@ class LayerCache:
     loop carries.  ``read(key)`` gives the layer's slice of ``stack[key]``;
     ``write(key, new)`` puts the layer's update in (:func:`write_layer`) and
     returns the slice as it is after the write, so attention reads the
-    current token from the same buffer the step updates in place."""
+    current token from the same buffer the step updates in place;
+    ``add(key, counts)`` sums into a counter the layers share."""
 
     def __init__(self, stack: dict, layer, write_at):
         self.stack, self.layer, self.write_at = stack, layer, write_at
@@ -234,6 +241,10 @@ class LayerCache:
     def writer(self, key: str):
         return lambda new: self.write(key, new)
 
+    def add(self, key: str, counts):
+        """Add to an accumulator of the whole stack (not per layer)."""
+        self.stack = {**self.stack, key: self.stack[key] + counts}
+
 
 # ------------------------------------------------------------------- Model
 class Model:
@@ -247,12 +258,18 @@ class Model:
         cfg = self.cfg
         k_emb, k_blocks, k_enc = jax.random.split(rng, 3)
         params = {"emb": init_embeddings(cfg, k_emb)}
-        block_init = init_xattn_block if cfg.n_enc_layers else init_block
         kd = jax.random.split(k_blocks, cfg.n_layers)
-        if cfg.scan_layers:
-            params["blocks"] = jax.vmap(lambda k: block_init(cfg, k))(kd)
-        else:
-            params["blocks"] = [block_init(cfg, k) for k in kd]
+        nd = cfg.n_dense_layers
+
+        def stack(keys, moe):
+            one = ((lambda k: init_xattn_block(cfg, k)) if cfg.n_enc_layers
+                   else (lambda k: init_block(cfg, k, moe)))
+            if cfg.scan_layers:
+                return jax.vmap(one)(keys)
+            return [one(k) for k in keys]
+        if nd:
+            params["dense"] = stack(kd[:nd], False)
+        params["blocks"] = stack(kd[nd:], cfg.is_moe)
         if cfg.n_enc_layers:
             ks = jax.random.split(k_enc, cfg.n_enc_layers)
             if cfg.scan_layers:
@@ -281,7 +298,10 @@ class Model:
         and no step copies it.  The decode scan is unrolled: a one-token
         step is small, and a rolled loop's own work (slicing every weight
         stack and the layer index, the trip counter, ops XLA cannot fuse
-        across the loop boundary) adds a quarter to its device ops."""
+        across the loop boundary) adds a quarter to its device ops.  An MoE
+        model's leading dense blocks (``params["dense"]``) are a stack of
+        their own that runs first, at layers ``0 .. n_dense_layers - 1`` of
+        the one stacked cache."""
         cfg = self.cfg
         slstm_sel = self._slstm_mask() if cfg.family == "ssm" else None
 
@@ -300,20 +320,24 @@ class Model:
 
         sel = (slstm_sel if slstm_sel is not None
                else jnp.zeros((cfg.n_layers, 1, 1, 1)))
-        if cfg.scan_layers:
-            fn = body
-            if cfg.remat:
-                policy = (jax.checkpoint_policies.save_only_these_names(
-                    "blk_attn_in", "blk_mlp_in")
-                    if cfg.remat_policy == "save_boundaries" else None)
-                fn = jax.checkpoint(body, prevent_cse=False, policy=policy)
-            (x, cache), _ = jax.lax.scan(
-                fn, (x, cache),
-                (params["blocks"], sel, jnp.arange(cfg.n_layers)),
-                unroll=cache is not None)
-            return x, cache
-        for i in range(cfg.n_layers):
-            (x, cache), _ = body((x, cache), (params["blocks"][i], sel[i], i))
+        nd = cfg.n_dense_layers
+        stacks = ([(params["dense"], 0, nd)] if nd else []) + [
+            (params["blocks"], nd, cfg.n_layers)]
+        fn = body
+        if cfg.scan_layers and cfg.remat:
+            policy = (jax.checkpoint_policies.save_only_these_names(
+                "blk_attn_in", "blk_mlp_in")
+                if cfg.remat_policy == "save_boundaries" else None)
+            fn = jax.checkpoint(body, prevent_cse=False, policy=policy)
+        for blocks, lo, hi in stacks:
+            if cfg.scan_layers:
+                (x, cache), _ = jax.lax.scan(
+                    fn, (x, cache), (blocks, sel[lo:hi], jnp.arange(lo, hi)),
+                    unroll=cache is not None)
+                continue
+            for i in range(lo, hi):
+                (x, cache), _ = body((x, cache),
+                                     (blocks[i - lo], sel[i], i))
         return x, cache
 
     def _encode(self, params, audio_embeds):
@@ -372,11 +396,16 @@ class Model:
                     "mamba": {k: (stack(v), jnp.float32)
                               for k, v in S.mamba_state_shape(cfg, batch).items()}}
             return spec
+        # MoE models count their routing in the cache (donated with it):
+        # pairs, experts touched, busiest expert's rows, layer-steps
+        moe = {"moe_stats": ((4,), jnp.int32)} if cfg.is_moe else {}
         if cfg.attention == "mla":
             return {"kv": {"c_kv": (stack((batch, max_seq, cfg.kv_lora_rank)), kd),
-                           "k_rope": (stack((batch, max_seq, cfg.rope_head_dim)), kd)}}
+                           "k_rope": (stack((batch, max_seq, cfg.rope_head_dim)), kd)},
+                    **moe}
         return {"kv": {"k": (stack((batch, max_seq, cfg.n_kv_heads, cfg.hd)), kd),
-                       "v": (stack((batch, max_seq, cfg.n_kv_heads, cfg.hd)), kd)}}
+                       "v": (stack((batch, max_seq, cfg.n_kv_heads, cfg.hd)), kd)},
+                **moe}
 
     def init_cache(self, batch: int, max_seq: int) -> dict:
         return jax.tree.map(lambda sd: jnp.zeros(sd[0], sd[1]),

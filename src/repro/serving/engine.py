@@ -44,6 +44,13 @@ from repro.models.config import ModelConfig
 from repro.telemetry.tracing import annotate
 from repro.telemetry.tracker import make_tracker
 
+#: ``stats`` keys of an MoE model's routing, in the order of the decode
+#: cache's ``moe_stats`` counters: token-expert pairs, experts with a row,
+#: the busiest expert's rows, layer-steps (each summed over MoE layers and
+#: decode steps of every slot, idle slots included).
+MOE_STATS = ("moe_rows", "moe_experts_touched", "moe_max_load",
+             "moe_layer_steps")
+
 
 @dataclasses.dataclass
 class EngineConfig:
@@ -113,6 +120,9 @@ class ServingEngine:
             tracker=self._trk))
         self._gen = {"generated_tokens": 0, "batches": 0,
                      "evicted_responses": 0}
+        if model_cfg.is_moe:
+            # summed over MoE layer-steps from the decode cache's counters
+            self._gen.update(dict.fromkeys(MOE_STATS, 0))
         self.cache.subscribe("evict", self._on_evict)
         self._recent_admits: list[int] = []          # admits since last scan
         self.cache.subscribe("admit",
@@ -335,5 +345,13 @@ class ServingEngine:
                     else:
                         cur[i] = tok
             try_fill()
+        if "moe_stats" in cache:
+            # the routing counters ride in the donated cache; one read a call
+            with annotate("rac/engine/moe_stats"):
+                counts = np.asarray(cache["moe_stats"])
+            for key, n in zip(MOE_STATS, counts.tolist()):
+                self._gen[key] += n
+                if self._trk is not None:
+                    self._trk.count(f"engine.{key}", n)
         self.cache.flush()           # settle admissions queued in the tail
         return sorted(done, key=lambda r: r.rid)

@@ -106,6 +106,41 @@ def test_decode_step_updates_the_cache_in_place():
     eng.close()
 
 
+@pytest.mark.parametrize("arch", ["deepseek_v2_lite_16b", "paper"])
+def test_engine_moe_counters(arch):
+    """An MoE model's routing counters reach ``stats`` and the tracker,
+    read once per ``run`` from the decode cache: every step routes each
+    slot's row (idle slots too) to top_k experts on every MoE layer.  A
+    dense model's engine has none."""
+    from repro.serving.engine import MOE_STATS
+    mcfg = smoke_variant(get_config(arch))
+    b = 4
+    eng = ServingEngine(mcfg, EngineConfig(cache_capacity=16,
+                                           max_new_tokens=3, max_batch=b,
+                                           max_seq=32, tracker="memory"))
+    space = EmbeddingSpace(dim=64, seed=13)
+    reqs = [(200 + i, space.content_embedding(i, 200 + i).astype(np.float32),
+             [2, 3]) for i in range(6)]
+    eng.run(reqs[:3])
+    eng.run(reqs[3:])
+    st = eng.stats
+    if not mcfg.is_moe:
+        assert not set(MOE_STATS) & set(st)
+        eng.close()
+        return
+    n_moe = mcfg.n_layers - mcfg.n_dense_layers
+    layer_steps = st["batches"] * n_moe
+    assert st["moe_layer_steps"] == layer_steps > 0
+    assert st["moe_rows"] == layer_steps * b * mcfg.top_k
+    assert layer_steps <= st["moe_experts_touched"] <= \
+        layer_steps * mcfg.n_experts
+    assert st["moe_rows"] // mcfg.n_experts <= st["moe_max_load"] <= \
+        layer_steps * b
+    for key in MOE_STATS:
+        assert eng.tracker.counter(f"engine.{key}") == st[key]
+    eng.close()
+
+
 # ------------------------------------------------------------ KV blocks
 def test_kv_prefix_reuse():
     mgr = KVBlockManager(n_blocks=64, block_tokens=4)

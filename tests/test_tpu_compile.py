@@ -179,3 +179,50 @@ def test_decode_step_keeps_the_cache_in_place(one_chip, scan):
     aliased = re.findall(r"\{\d+\}: \((\d+), \{\}", text)
     assert {int(a) for a in aliased} == set(
         range(n_params, n_params + len(jax.tree.leaves(cache))))
+
+
+def test_dsv2_lite_decode_step_fits_and_keeps_the_cache_in_place(one_chip):
+    """DeepSeek-V2-Lite's decode step as the served cell runs it: 9 layers
+    (the dense one and 8 MoE) at every published width, 128 slots of 256
+    positions, bfloat16, layers laid out as the preset lays them out
+    (unstacked: stacked, each layer's expert slice is copied for the
+    grouped matmul kernel, 5.87 GB of temporaries, and the check on them
+    fails).  The TPU program fits the chip beside its 10.7 GB of weights,
+    aliases both MLA cache leaves and the MoE counters input to output,
+    copies no cache-sized buffer, and runs the experts through the grouped
+    matmul kernel."""
+    import dataclasses
+    from repro.configs import get_config
+    from repro.serving import EngineConfig, ServingEngine
+    b, s = 128, 256
+    cfg = dataclasses.replace(get_config("deepseek_v2_lite_16b"), n_layers=9)
+    eng = ServingEngine(cfg, EngineConfig(max_batch=b, max_seq=s),
+                        params=jax.eval_shape(
+                            build_model(cfg).init, jax.random.PRNGKey(0)))
+    on_chip = functools.partial(jax.tree.map, lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip))
+    cache = on_chip(eng.model.cache_shape_structs(b, s))
+    batch = on_chip({"tokens": jax.ShapeDtypeStruct((b, 1), I32),
+                     "pos": jax.ShapeDtypeStruct((b,), I32)})
+    compiled = eng.decode.lower(on_chip(eng.params), cache, batch).compile()
+    text = compiled.as_text()
+    eng.close()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert used < 15.75 * 2 ** 30, used
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20, mem.temp_size_in_bytes
+    # 3 grouped matmuls (up, gate, down) on each of the 8 MoE layers
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = [^\n]*"
+                          r'custom_call_target="tpu_custom_call"', text)) == 24
+    for leaf in jax.tree.leaves(cache)[:2]:
+        shape = ",".join(map(str, leaf.shape))
+        assert not re.findall(rf"= bf16\[{shape}\]\{{[^}}]*\}} "
+                              r"(copy|broadcast)\(", text)
+        inner = ",".join(map(str, leaf.shape[1:]))
+        assert not re.findall(rf"= bf16\[(1,)?{inner}\]\{{[^}}]*\}} copy\(",
+                              text)
+    n_params = len(jax.tree.leaves(eng.params))
+    aliased = re.findall(r"\{\d+\}: \((\d+), \{\}", text)
+    assert {int(a) for a in aliased} == set(
+        range(n_params, n_params + len(jax.tree.leaves(cache))))
