@@ -547,7 +547,8 @@ class KernelBackend:
 
     The fused decision path keeps the whole scoring state device-resident:
     three :class:`_DeviceMirror`\\ s hold the embedding slab + occupancy
-    (synced against the store's mutation journal), the policy table's slot
+    (synced against the store's mutation journal, and scanned by the exact
+    lookup as well), the policy table's slot
     slabs (tsi/topic, its slot journal), and its topic tables (TP state +
     representatives, its topic journal).  Steady-state replay therefore
     moves O(mutated rows) per chunk, not O(capacity).
@@ -639,8 +640,8 @@ class KernelBackend:
         """Launch/transfer observability (``ops.dispatch_stats``): jitted
         dispatches issued, blocking device→host syncs, host wall seconds
         from dispatch to ready (``kernel_s``: uploads of host arguments
-        included, not kernel time), and the explicit host→device slab
-        uploads (``upload_s``, ``uploads``).  Process-global (the jit
+        included, not kernel time), and ``upload_s``/``uploads``, which
+        stay at zero (see ``ops.dispatch_stats``).  Process-global (the jit
         caches are too) — consumers read deltas."""
         from repro.kernels import ops
         return dict(ops.dispatch_stats)
@@ -664,19 +665,32 @@ class KernelBackend:
             return self._top1_batch_quantized(store, queries)
         return self._top1_batch_exact(store, queries)
 
+    def _store_slab(self, store: ResidentStore) -> dict:
+        """``_store_mirror`` freshened against ``store``: nothing when the
+        version is unchanged, a dirty-row scatter, or a full upload (churn,
+        or a store of another lineage, such as an arena view)."""
+        return self._store_mirror.sync(
+            store.version, store.dirty_since,
+            lambda: {"emb": store.emb, "occ": store.occ})
+
     def _top1_batch_exact(self, store: ResidentStore,
                           queries: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-        from repro.kernels import ops                  # deferred: jax import
+        import jax                                     # deferred, as ops is
+
+        from repro.kernels import ops
         b = queries.shape[0]
         if not store.slot_of:
             return (np.full(b, -1, dtype=np.int64),
                     np.full(b, -np.inf, dtype=np.float64))
         pad = (-b) % self.q_pad
         qp = np.pad(queries, ((0, pad), (0, 0))) if pad else queries
-        # the slab goes to the device in a span of its own, ended when the
-        # transfer is done, so a trace tells the upload from the scan
+        # the slab comes from the journal-synced mirror, in a span of its
+        # own that ends when the sync's transfer is done, so a trace tells
+        # it from the scan.  Unbound: the sharded backend runs this body
+        # for dense arena views
         with annotate("rac/upload"):
-            slab = ops.upload(store.emb)
+            slab = jax.block_until_ready(
+                KernelBackend._store_slab(self, store)["emb"])
         # runtime n_valid = the store's high-water mark: slots past it have
         # never been occupied, so the kernel skips scoring the free tail
         # (one compilation — the count is scalar-prefetched, not baked in)
@@ -690,6 +704,7 @@ class KernelBackend:
         cids = store.cid[idx].copy()
         # a free (zeroed) slot can only win when all real sims < 0 → miss
         sims = np.where(cids >= 0, vals, -np.inf)
+        self._flush_sync()
         return cids, sims
 
     def _top1_batch_quantized(self, store: ResidentStore,
@@ -809,9 +824,7 @@ class KernelBackend:
         from repro.kernels import fused, ops
         b, dim = queries.shape
         cfg = self.quantized
-        slab = self._store_mirror.sync(
-            store.version, store.dirty_since,
-            lambda: {"emb": store.emb, "occ": store.occ})
+        slab = self._store_slab(store)
         bq = fused.pad_pow2(b, 1)       # pow2 bucket, floor 1 (serving b=1)
         qp, q8q, qsc, ql1 = fused.prep_queries(queries, bq)
         n_slots = store.emb.shape[0]
@@ -848,9 +861,7 @@ class KernelBackend:
         mirror is maintained even without a composed quantized config —
         the fused candidate scan is always int8 (see docs)."""
         qm = self._qhost.sync(store.version, store.dirty_since, store.emb)
-        slab = self._store_mirror.sync(
-            store.version, store.dirty_since,
-            lambda: {"emb": store.emb, "occ": store.occ})
+        slab = KernelBackend._store_slab(self, store)
         q8d = self._q8_mirror.sync(
             store.version, store.dirty_since,
             lambda: {"q8": qm.q8, "scale": qm.scale, "l1": qm.l1})
@@ -1263,9 +1274,7 @@ class KernelBackend:
 
     def _device_state(self, store: ResidentStore, table: PolicyTable) -> dict:
         """The mirrored decision state, freshened by dirty-row scatter."""
-        slab = self._store_mirror.sync(
-            store.version, store.dirty_since,
-            lambda: {"emb": store.emb, "occ": store.occ})
+        slab = self._store_slab(store)
         slot = self._slot_mirror.sync(
             table.slot_version, table.dirty_slots_since,
             lambda: {"tsi": table.tsi, "tid": table.topic_of})

@@ -190,8 +190,8 @@ class ShardedKernelBackend:
         """Launch/transfer observability (``ops.dispatch_stats``): jitted
         dispatches issued, blocking device→host syncs, host wall seconds
         from dispatch to ready (``kernel_s``: uploads of host arguments
-        included, not kernel time), and the explicit host→device slab
-        uploads (``upload_s``, ``uploads``).  Process-global (the jit
+        included, not kernel time), and ``upload_s``/``uploads``, which
+        stay at zero (see ``ops.dispatch_stats``).  Process-global (the jit
         caches are too) — consumers read deltas."""
         from repro.kernels import ops
         return dict(ops.dispatch_stats)

@@ -34,8 +34,10 @@ def _is_cpu() -> bool:
 #: counts device→host materializations (every ``to_host``), ``kernel_s``
 #: accumulates the host wall time of ``run_timed`` intervals — dispatch to
 #: ready, so it includes the upload of any host array passed in and is not
-#: kernel time — and ``upload_s``/``uploads`` time and count the explicit
-#: host→device transfers made through :func:`upload`.
+#: kernel time.  ``upload_s``/``uploads`` stay at zero: no path makes an
+#: explicit slab upload (the exact scan reads the journal-synced store
+#: mirror, whose transfers the mirrors' ``sync_stats`` count); they remain
+#: for readers of the ledger that still expect the keys.
 dispatch_stats = {"launches": 0, "host_syncs": 0, "kernel_s": 0.0,
                   "upload_s": 0.0, "uploads": 0}
 
@@ -58,18 +60,6 @@ def to_host_tuple(xs):
     fused pipeline's single device→host transfer per chunk."""
     dispatch_stats["host_syncs"] += 1
     return jax.device_get(xs)
-
-
-def upload(x):
-    """Hand the host array ``x`` to the default device and wait until the
-    transfer is done, charging the interval to ``dispatch_stats["upload_s"]``
-    (and one to ``uploads``), so the transfer is timed apart from the
-    kernel that reads it."""
-    t0 = time.perf_counter()
-    out = jax.block_until_ready(jax.device_put(x))
-    dispatch_stats["upload_s"] += time.perf_counter() - t0
-    dispatch_stats["uploads"] += 1
-    return out
 
 
 def run_timed(fn):

@@ -198,6 +198,114 @@ def test_content_mode_lookup_batch():
     assert [r.hit for r in rs] == [True, True, False, False]
 
 
+# ------------------------------------------ exact lookups on the store mirror
+def _answers(results):
+    return [(r.cid, r.sim) if r.hit else (r.best_cid, r.best_sim)
+            for r in results]
+
+
+def _lookup_both(ck, cn, queries):
+    """One ``lookup_batch`` on the kernel and the host facade.  Decisions
+    match the host backend's; the kernel's (cid, sim) are bit-equal to the
+    same scan of a freshly uploaded slab, so the mirror is never stale."""
+    fresh = KernelBackend(use_pallas=False).top1_batch(ck.store, queries)
+    got, want = (_answers(c.lookup_batch(queries)) for c in (ck, cn))
+    assert [c for c, _ in got] == [c for c, _ in want]
+    np.testing.assert_allclose([s for _, s in got], [s for _, s in want],
+                               atol=1e-6)
+    assert got == list(zip(fresh[0].tolist(), fresh[1].tolist()))
+    return [c for c, _ in got]
+
+
+@pytest.mark.parametrize("case", ["evict", "churn", "foreign", "arena",
+                                  "restore"])
+def test_exact_lookup_scans_the_fresh_mirror(case):
+    """The kernel backend's exact lookup scans its journal-synced store
+    mirror, interleaved with admissions that evict: a dirty-row scatter per
+    lookup after one full sync, a full sync after churn past
+    ``small_delta``, and fresh answers when a store of another lineage (a
+    diverged copy, an arena view) or a restored snapshot passes through
+    the same backend."""
+    import copy
+
+    from repro.cache.backends import small_delta
+    from repro.core.arena import ArenaStore
+    dim, cap = 32, 24 if case != "churn" else 100
+    space = EmbeddingSpace(dim=dim, seed=7)
+    kb = KernelBackend(use_pallas=False)
+    ck, cn = (SemanticCache(CacheConfig(capacity=cap, dim=dim, backend=b,
+                                        policy="LRU", tau_hit=0.85))
+              for b in (kb, "numpy"))
+    emb = [space.content_embedding(i % 9, i).astype(np.float32)
+           for i in range(3 * cap)]
+
+    def admit(i):
+        out = [c.admit(i, emb[i]) for c in (ck, cn)]
+        assert out[0] == out[1]
+        return out[0]
+
+    def queries(ids):
+        return np.stack([space.paraphrase(emb[i], i % 9, i, 1)
+                         .astype(np.float32) for i in ids] + [emb[0]])
+
+    for i in range(cap):
+        admit(i)
+    _lookup_both(ck, cn, queries(range(0, cap, 3)))
+    s0 = dict(kb.sync_stats)
+    assert s0["full"] == 1                  # the first scan fills the mirror
+
+    if case == "evict":
+        for i in range(cap, cap + 8):
+            (gone,) = admit(i)              # frees the victim's slot
+            hits = _lookup_both(ck, cn, queries([gone, i, i - 1]))
+            assert gone not in hits
+        s1 = kb.sync_stats
+        assert s1["full"] == s0["full"]
+        assert s1["incremental"] == s0["incremental"] + 8
+    elif case == "churn":
+        v = ck.store.version
+        for i in range(cap, 2 * cap):
+            admit(i)
+        assert not small_delta(len(ck.store.dirty_since(v)),
+                               ck.store.emb.shape[0])
+        _lookup_both(ck, cn, queries(range(cap, 2 * cap, 5)))
+        assert kb.sync_stats["full"] == s0["full"] + 1
+        assert kb.sync_stats["incremental"] == s0["incremental"]
+    elif case in ("foreign", "arena"):
+        if case == "foreign":               # same history, then diverges
+            other = copy.deepcopy(ck.store)
+            other.remove(next(iter(other.slot_of)))
+            other.insert(10_000, emb[-1])
+        else:
+            arena = ArenaStore(2, cap, dim, track_rows=True)
+            for i in range(cap):
+                arena.views[i % 2].insert(i, emb[-1 - i])
+            other = arena.views[1]
+        q = queries([0, 3 * cap - 1, 3 * cap - 2])
+        for i in range(cap, cap + 3):       # alternate the two lineages
+            want = NumpyBackend().top1_batch(other, q)
+            got = kb.top1_batch(other, q)
+            np.testing.assert_array_equal(got[0], want[0])
+            np.testing.assert_allclose(got[1], want[1], atol=1e-6)
+            _lookup_both(ck, cn, q)         # never answered from `other`
+            admit(i)
+            other.remove(next(iter(other.slot_of)))
+            other.insert(20_000 + i, emb[i])
+    else:
+        snaps = [c.checkpoint() for c in (ck, cn)]
+        for i in range(cap, cap + 4):
+            admit(i)
+        _lookup_both(ck, cn, queries(range(cap, cap + 4)))
+        for c, snap in zip((ck, cn), snaps):
+            c.restore(snap)                 # back to rows the mirror left
+        _lookup_both(ck, cn, queries(range(cap, cap + 4)))
+        s1 = dict(kb.sync_stats)
+        admit(cap + 5)                      # the mirror follows the restored
+        _lookup_both(ck, cn, queries([cap + 5]))      # store from now on
+        assert kb.sync_stats["full"] == s1["full"]
+        assert kb.sync_stats["incremental"] == s1["incremental"] + 1
+
+
 # ------------------------------------------------------ sharded store parity
 def _replay_decisions(trace, capacity, backend, n_requests=2000, **bkw):
     """Replay a trace slice through the facade, recording every decision

@@ -430,11 +430,13 @@ def test_tracker_spans_show_in_the_profiler_trace(tmp_path):
 
 
 def test_victim_and_upload_counters(parity_trace):
-    """``victim_s`` and the dispatch ledger's ``upload_s``/``uploads``
-    advance on the kernel backend; on the host backend ``victim_s``
-    advances the same way and no upload is counted.  Decisions agree."""
+    """``victim_s`` advances on both backends.  The kernel backend's exact
+    lookup scans the journal-synced store mirror: one full sync on the
+    first ``lookup_batch`` after the fill, a dirty-row scatter after one
+    more admission, and no explicit slab upload either time.  The host
+    backend counts no upload.  Decisions agree."""
     from repro.kernels import ops
-    reqs = parity_trace.requests[:80]
+    reqs = parity_trace.requests[:81]
     queries = np.stack([r.emb for r in reqs[:5]])
     out = {}
     for backend in ("numpy", "kernel"):
@@ -442,7 +444,7 @@ def test_victim_and_upload_counters(parity_trace):
             capacity=24, dim=16, tau_hit=0.85, backend=backend,
             use_pallas=False))
         evicted = []
-        for r in reqs:
+        for r in reqs[:80]:
             evicted += cache.admit(r.cid, r.emb, t=r.t)
         before = cache.metrics_snapshot()
         up0 = dict(ops.dispatch_stats)
@@ -451,15 +453,23 @@ def test_victim_and_upload_counters(parity_trace):
         assert snap["evictions"] == len(evicted) > 0
         assert 0.0 < snap["victim_s"] <= snap["admit_s"]
         assert {"upload_s", "uploads"} <= set(snap["dispatch"])
-        uploads = snap["dispatch"]["uploads"] - before["dispatch"]["uploads"]
-        upload_s = (snap["dispatch"]["upload_s"]
-                    - before["dispatch"]["upload_s"])
+        r = reqs[80]
+        evicted += cache.admit(r.cid, r.emb, t=r.t)
+        res += cache.lookup_batch(queries)
+        again = cache.metrics_snapshot()
         if backend == "kernel":
-            assert uploads == ops.dispatch_stats["uploads"] - up0["uploads"]
-            assert uploads == 1 and upload_s > 0.0   # one slab per batch
+            assert ops.dispatch_stats["uploads"] == up0["uploads"]
+            for a, b in ((before, snap), (snap, again)):
+                assert b["dispatch"]["uploads"] == a["dispatch"]["uploads"]
+            # the first scan after the fill uploads the mirror once ...
+            assert snap["sync"]["full"] == before["sync"]["full"] + 1
+            # ... and after one more admission only its rows are scattered
+            assert again["sync"]["full"] == snap["sync"]["full"]
+            assert (again["sync"]["incremental"]
+                    == snap["sync"]["incremental"] + 1)
         else:
-            assert (snap["dispatch"]["uploads"],
-                    snap["dispatch"]["upload_s"]) == (0, 0.0)
+            assert (again["dispatch"]["uploads"],
+                    again["dispatch"]["upload_s"]) == (0, 0.0)
         out[backend] = (evicted, [(r.hit, getattr(r, "cid", None))
                                   for r in res])
     assert out["numpy"] == out["kernel"]
